@@ -1,8 +1,8 @@
-"""Kernel micro-benchmarks: fused FrODO update (Pallas, interpret on CPU)
-vs the unfused pure-jnp reference, plus the analytic HBM-traffic model that
-motivates the fusion on TPU (the wall-clock here is CPU interpret-mode and
-NOT indicative of TPU perf; the derived column is the modelled HBM bytes
-moved per step, which is hardware-independent)."""
+"""Kernel micro-benchmarks: fused FrODO update (Pallas) vs the unfused
+pure-jnp reference, plus the analytic HBM-traffic model that motivates the
+fusion on TPU (the derived column is the modelled HBM bytes moved per step,
+which is hardware-independent).  The Pallas rows need a TPU: off it the
+kernels raise rather than fall back to interpret mode."""
 from __future__ import annotations
 
 import time

@@ -1,1 +1,2 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU)."""
+"""Pallas TPU kernels (checked on the CPU in Pallas' TPU interpret mode,
+under tests only)."""

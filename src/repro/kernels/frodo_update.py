@@ -10,10 +10,16 @@ while the kernels do a single pass with the M accumulator resident in VMEM.
 Layout: callers (ops.py) flatten the parameter to 2-D (R, 128) tiles; the
 grid walks row-blocks; each program holds a (T|K, BR, 128) history tile and
 a (BR, 128) accumulator in VMEM.  BR is chosen so the working set stays
-under ~4 MiB of the 16 MiB VMEM.
+under ~4 MiB of the 16 MiB VMEM (double-buffered by the pipeline: ~8 MiB).
+BR is a multiple of 16 rows, which meets both the f32 (8, 128) and the bf16
+(16, 128) tiling, or the whole of R when R is smaller; the last block may
+be ragged.  The per-slot weights, rates and coefficients are scalars read
+by index, so they live in SMEM.
 
-Kernels are validated on CPU in interpret mode against kernels/ref.py; on a
-real TPU the same `pl.pallas_call` lowers to Mosaic.
+Off the TPU a kernel runs only in Pallas' interpret mode, and only when the
+caller asks for it around the call (tests use
+``jax.experimental.pallas.tpu.force_tpu_interpret_mode``); without that
+request Pallas refuses to lower the kernel for another backend.
 """
 from __future__ import annotations
 
@@ -22,20 +28,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
+SUBLANE = 16                 # row multiple that tiles both f32 and bf16
+VMEM_BUDGET = 4 * 2 ** 20    # bytes of one pipeline buffer set
+MAX_BR = 512
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _pick_br(R: int, slots: int, itemsize: int) -> int:
+    """Rows per program: keep (slots + 2) * BR * LANE * itemsize under the
+    VMEM budget with BR a multiple of SUBLANE, or take all of R if fewer."""
+    br = VMEM_BUDGET // ((slots + 2) * LANE * itemsize)
+    br = min(MAX_BR, max(SUBLANE, br // SUBLANE * SUBLANE))
+    return R if R <= br else br
 
 
-def _pick_br(T: int, itemsize: int, vmem_budget: int = 4 * 2 ** 20) -> int:
-    """Rows per program: keep (T+2) * BR * LANE * itemsize under budget,
-    BR a multiple of 8 (fp32 sublane)."""
-    br = vmem_budget // ((T + 2) * LANE * itemsize)
-    br = max(8, (br // 8) * 8)
-    return min(br, 512)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ------------------------------------------------------------------ exact
@@ -58,22 +67,18 @@ def exact_update_2d(g2: jax.Array, hist2: jax.Array, w_slot: jax.Array,
     Returns delta (R, LANE).  (History push is a cheap XLA dynamic-update
     done by the caller — rewriting all T slots would defeat the point.)"""
     T, R, _ = hist2.shape
-    br = min(_pick_br(T, hist2.dtype.itemsize), R)
-    while R % br:
-        br //= 2
-    br = max(br, 1)
-    grid = (R // br,)
+    br = _pick_br(R, T, hist2.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_exact_kernel, T=T, alpha=alpha, beta=beta),
-        grid=grid,
+        grid=(pl.cdiv(R, br),),
         in_specs=[
-            pl.BlockSpec((T,), lambda i: (0,)),
+            _SMEM,
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((T, br, LANE), lambda i: (0, i, 0)),
         ],
         out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, LANE), g2.dtype),
-        interpret=_interpret(),
+        name="frodo_exact_update",
     )(w_slot.astype(jnp.float32), g2, hist2)
 
 
@@ -92,19 +97,16 @@ def _expsum_kernel(r_ref, c_ref, g_ref, acc_ref, delta_ref, newacc_ref,
 
 def expsum_update_2d(g2: jax.Array, acc2: jax.Array, rates: jax.Array,
                      coeffs: jax.Array, alpha: float, beta: float):
-    """g2: (R, LANE); acc2: (K, R, LANE).  Returns (delta, new_acc)."""
+    """g2: (R, LANE); acc2: (K, R, LANE).  Returns (delta, new_acc); the new
+    accumulators are written over ``acc2``'s buffer, block by block."""
     K, R, _ = acc2.shape
-    br = min(_pick_br(2 * K, acc2.dtype.itemsize), R)
-    while R % br:
-        br //= 2
-    br = max(br, 1)
-    grid = (R // br,)
+    br = _pick_br(R, 2 * K, acc2.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_expsum_kernel, K=K, alpha=alpha, beta=beta),
-        grid=grid,
+        grid=(pl.cdiv(R, br),),
         in_specs=[
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
             pl.BlockSpec((K, br, LANE), lambda i: (0, i, 0)),
         ],
@@ -116,5 +118,6 @@ def expsum_update_2d(g2: jax.Array, acc2: jax.Array, rates: jax.Array,
             jax.ShapeDtypeStruct((R, LANE), g2.dtype),
             jax.ShapeDtypeStruct(acc2.shape, acc2.dtype),
         ],
-        interpret=_interpret(),
+        input_output_aliases={3: 1},
+        name="frodo_expsum_update",
     )(rates.astype(jnp.float32), coeffs.astype(jnp.float32), g2, acc2)

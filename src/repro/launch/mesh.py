@@ -3,17 +3,12 @@ touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types: the compiler propagates
+    shardings from the constraints the model code sets."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -25,7 +20,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (CPU tests / examples)."""
+    """Mesh over this host's devices: ``data`` x ``model``.  The trainer
+    puts its agents on ``data`` (``Trainer(mesh=...)``)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
     return make_mesh_auto((n // model_parallel, model_parallel),

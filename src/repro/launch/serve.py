@@ -167,6 +167,8 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=8)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.batch is not None:
         _run_static(args)
     else:

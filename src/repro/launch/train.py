@@ -1,12 +1,15 @@
 """Training launcher.
 
-On the production fleet this process runs per host with a real TPU mesh;
-here it runs the same code path on however many devices exist (optionally
-forced host devices via --force-devices, which must be set before jax
-initializes — hence the env re-exec guard).
+Runs on however many devices exist: everything on one device, or with
+``--mesh`` the agents spread over all of them (optionally forced host
+devices via --force-devices, which must be set before jax initializes —
+hence the env re-exec guard).
 
     PYTHONPATH=src python -m repro.launch.train --arch h2o-danube-1.8b \
         --smoke --steps 20 --agents 4
+
+``--layers`` cuts a published config in depth (widths untouched); the
+one-chip cut of h2o-danube-1.8b is ``CHIP_TRAIN`` in its config module.
 
 ``run_training`` is the importable entry point (used by the golden-run
 regression harness, see benchmarks/regress.py): same seed -> same data
@@ -17,18 +20,53 @@ import os
 import sys
 
 
-def run_training(arch: str = "h2o-danube-1.8b", smoke: bool = True,
-                 steps: int = 20, agents: int = 2, seq: int = 128,
-                 batch_per_agent: int = 2, optimizer: str = "frodo",
-                 alpha: float = 0.02, beta: float = 0.008,
-                 lam: float = 0.15, T: int = 40,
-                 memory_mode: str = "exact", topology: str = "complete",
-                 consensus_interval: int = 1, ckpt_dir: str = "checkpoints",
-                 metrics_out: str = "", collect_metrics: bool = False,
-                 seed: int = 0, profile_dir: str = "",
-                 profile_start: int = 0, profile_stop: int = 4,
-                 spans_out: str = ""):
-    """Run the training loop; returns the trainer (history attached).
+def build_trainer(arch: str = "h2o-danube-1.8b", smoke: bool = True,
+                  layers: int = 0, agents: int = 2, seq: int = 128,
+                  batch_per_agent: int = 2, optimizer: str = "frodo",
+                  alpha: float = 0.02, beta: float = 0.008,
+                  lam: float = 0.15, T: int = 40,
+                  memory_mode: str = "exact", K: int = 8,
+                  acc_dtype: str = "float32", use_kernel: bool = False,
+                  topology: str = "complete", consensus_interval: int = 1,
+                  collect_metrics: bool = False, mesh=None, **trainer_kw):
+    """The ``Trainer`` that ``run_training`` drives, built without touching
+    a device (so a test can lower its step for a described chip).
+
+    ``smoke`` picks the arch's reduced CPU config; otherwise the published
+    config, cut to ``layers`` layers by ``registry.reduced_layers`` when
+    ``layers`` is set, every width untouched.  ``mesh`` (a ``Mesh`` with a
+    ``data`` axis) spreads the agents over its devices."""
+    from repro.configs import registry as REG
+    from repro.training.trainer import Trainer
+    from repro.training.train_step import TrainConfig
+
+    cfg = REG.get_smoke_config(arch) if smoke else REG.get_config(arch)
+    if layers:
+        cfg = REG.reduced_layers(cfg, layers)
+    tc = TrainConfig(optimizer=optimizer, alpha=alpha, beta=beta,
+                     lam=lam, T=T, memory_mode=memory_mode, K=K,
+                     acc_dtype=acc_dtype, use_kernel=use_kernel,
+                     remat=not smoke, topology=topology,
+                     consensus_interval=consensus_interval,
+                     collect_metrics=collect_metrics)
+    return Trainer(cfg, tc, n_agents=agents, mesh=mesh,
+                   tokens_per_step=agents * batch_per_agent * seq,
+                   **trainer_kw)
+
+
+def run_training(steps: int = 20, agents: int = 2, seq: int = 128,
+                 batch_per_agent: int = 2, mesh: bool = False,
+                 ckpt_dir: str = "checkpoints", metrics_out: str = "",
+                 collect_metrics: bool = False, seed: int = 0,
+                 profile_dir: str = "", profile_start: int = 0,
+                 profile_stop: int = 4, spans_out: str = "", **model_kw):
+    """Run the training loop; returns ``(trainer, state)``: the trainer
+    (history attached) and the final train state.
+
+    ``model_kw`` are ``build_trainer``'s model and optimizer arguments
+    (``arch``, ``smoke``, ``layers``, ``memory_mode``, ...); ``mesh`` puts
+    the agents over every device of the host
+    (``launch/mesh.py:make_host_mesh``).
 
     ``seed`` threads through both the parameter init and the synthetic
     token pipeline, so a fixed seed gives deterministic loss/grad-norm
@@ -42,42 +80,32 @@ def run_training(arch: str = "h2o-danube-1.8b", smoke: bool = True,
     Chrome trace-event file for Perfetto / ``repro.obs.report``.
     """
     from repro import obs
-    from repro.configs import registry as REG
     from repro.data.synthetic import TokenPipeline, augment_modalities
-    from repro.training.trainer import Trainer
-    from repro.training.train_step import TrainConfig
+    from repro.launch.mesh import make_host_mesh
 
-    cfg = REG.get_smoke_config(arch) if smoke else REG.get_config(arch)
-    collect = collect_metrics or bool(metrics_out)
-    tc = TrainConfig(optimizer=optimizer, alpha=alpha, beta=beta,
-                     lam=lam, T=T, memory_mode=memory_mode, remat=not smoke,
-                     topology=topology,
-                     consensus_interval=consensus_interval,
-                     collect_metrics=collect)
     sink = obs.JsonlSink(metrics_out) if metrics_out else None
-    tokens_per_step = agents * batch_per_agent * seq
-    trainer = Trainer(cfg, tc, n_agents=agents,
-                      ckpt_dir=ckpt_dir, log_every=5, sink=sink,
-                      tokens_per_step=tokens_per_step,
-                      profile_dir=profile_dir or None,
-                      profile_start=profile_start,
-                      profile_stop=profile_stop)
+    trainer = build_trainer(
+        agents=agents, seq=seq, batch_per_agent=batch_per_agent,
+        collect_metrics=collect_metrics or bool(metrics_out),
+        mesh=make_host_mesh() if mesh else None, ckpt_dir=ckpt_dir,
+        log_every=5, sink=sink, profile_dir=profile_dir or None,
+        profile_start=profile_start, profile_stop=profile_stop, **model_kw)
     state = trainer.init(seed=seed)
     data = augment_modalities(
-        iter(TokenPipeline(vocab=cfg.vocab, seq_len=seq,
+        iter(TokenPipeline(vocab=trainer.cfg.vocab, seq_len=seq,
                            batch_per_agent=batch_per_agent,
-                           n_agents=agents, seed=seed)), cfg)
+                           n_agents=agents, seed=seed)), trainer.cfg)
     recorder = obs.SpanRecorder() if spans_out else None
     prev = obs.set_recorder(recorder) if recorder is not None else None
     try:
-        trainer.run(state, data, steps)
+        state = trainer.run(state, data, steps)
     finally:
         if recorder is not None:
             obs.set_recorder(prev)
             recorder.save(spans_out, process_name="repro.launch.train")
         if sink is not None:
             sink.close()
-    return trainer
+    return trainer, state
 
 
 def main():
@@ -85,6 +113,9 @@ def main():
     ap.add_argument("--arch", default="h2o-danube-1.8b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced smoke config (CPU-friendly)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the published config to this many layers "
+                         "(widths untouched); 0 keeps its depth")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--agents", type=int, default=2)
     ap.add_argument("--seq", type=int, default=128)
@@ -96,9 +127,17 @@ def main():
     ap.add_argument("--T", type=int, default=40)
     ap.add_argument("--memory-mode", default="exact",
                     choices=("exact", "expsum"))
+    ap.add_argument("--K", type=int, default=8,
+                    help="exponentials of the expsum memory")
+    ap.add_argument("--acc-dtype", default="float32",
+                    choices=("float32", "bfloat16"))
+    ap.add_argument("--use-kernel", action="store_true",
+                    help="fused Pallas update (needs a TPU)")
     ap.add_argument("--topology", default="complete")
     ap.add_argument("--consensus-interval", type=int, default=1)
     ap.add_argument("--force-devices", type=int, default=0)
+    ap.add_argument("--mesh", action="store_true",
+                    help="spread the agents over every device of the host")
     ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--seed", type=int, default=0,
                     help="seeds init + data stream (deterministic run)")
@@ -122,13 +161,17 @@ def main():
             f"--xla_force_host_platform_device_count={args.force_devices}")
         os.execv(sys.executable, [sys.executable] + sys.argv)
 
-    run_training(arch=args.arch, smoke=args.smoke, steps=args.steps,
-                 agents=args.agents, seq=args.seq,
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    run_training(arch=args.arch, smoke=args.smoke, layers=args.layers,
+                 steps=args.steps, agents=args.agents, seq=args.seq,
                  batch_per_agent=args.batch_per_agent,
                  optimizer=args.optimizer, alpha=args.alpha, beta=args.beta,
                  lam=args.lam, T=args.T, memory_mode=args.memory_mode,
-                 topology=args.topology,
+                 K=args.K, acc_dtype=args.acc_dtype,
+                 use_kernel=args.use_kernel, topology=args.topology,
                  consensus_interval=args.consensus_interval,
+                 mesh=args.mesh,
                  ckpt_dir=args.ckpt_dir, metrics_out=args.metrics_out,
                  collect_metrics=args.collect_metrics, seed=args.seed,
                  profile_dir=args.profile_dir,

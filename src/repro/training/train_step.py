@@ -377,8 +377,12 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, n_agents: int,
                     out_metrics.setdefault(k, v)
             out_metrics["consensus_error_pre_mix"] = \
                 obs_metrics.consensus_error(pre_mix)
+            # measured on the parameters as stored: without the barrier
+            # XLA may feed the metric's agent mean the unrounded f32 mix
+            # (excess precision), and on four v5e chips it then reported
+            # disagreement between agents whose bf16 parameters were equal
             out_metrics["consensus_error"] = obs_metrics.consensus_error(
-                params)
+                jax.lax.optimization_barrier(params))
             out_metrics["param_norm"] = obs_metrics.global_norm(params)
             if faults is not None:
                 t = jnp.mod(state.step, fault_u.shape[0])

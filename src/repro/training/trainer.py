@@ -5,6 +5,13 @@ Telemetry: every step's scalar metrics (the aux pytree returned by
 host-side step-timing counters and drained into ``sink`` (any
 ``obs.MetricsSink``).  ``metrics_file`` keeps the legacy end-of-run JSON
 history; ``sink`` is the per-step JSONL/streaming path.
+
+The step donates the state it is given: a caller keeps only the state that
+``run`` (or the step) returns.  Given a ``mesh`` with a ``data`` axis (see
+``launch/mesh.py:make_host_mesh``), the agent axis of the state and the
+batch is placed on ``data`` by ``train_step.train_state_specs`` and the step
+is traced under the same logical-axis rules, so each device holds its
+agents' share; without one, everything lives on the default device.
 """
 from __future__ import annotations
 
@@ -16,12 +23,16 @@ from typing import Any, Dict, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.configs.base import ModelConfig
+from repro.distributed import sharding as SH
 from repro.training import checkpoint as ckpt
 from repro.training.train_step import (TrainConfig, TrainState,
-                                       init_train_state, make_train_step)
+                                       abstract_train_state, build_rules,
+                                       init_train_state, make_train_step,
+                                       train_state_specs)
 
 
 @dataclasses.dataclass
@@ -39,15 +50,40 @@ class Trainer:
     profile_dir: Optional[str] = None   # jax.profiler capture target
     profile_start: int = 0              # capture window: steps
     profile_stop: int = 4               # [profile_start, profile_stop]
+    mesh: Optional[Mesh] = None         # agents over its "data" axis
 
     def __post_init__(self):
+        step = make_train_step(self.cfg, self.tc, self.n_agents, self.n_pods)
+        self._history: list[Dict[str, Any]] = []
+        if self.mesh is None:
+            self.state_shardings = None
+            self.step_fn = jax.jit(step, donate_argnums=0)
+            return
+        rules = build_rules(self.cfg, multi_pod=False)
+        specs = train_state_specs(
+            abstract_train_state(self.cfg, self.tc, self.n_agents), self.cfg,
+            rules, self.mesh)
+        self.state_shardings = jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        batch_sharding = NamedSharding(
+            self.mesh, SH.logical_to_spec(("agent",), rules))
+
+        def step_on_mesh(state, batch):
+            with SH.use_rules(rules, self.mesh):
+                return step(state, batch)
+
         self.step_fn = jax.jit(
-            make_train_step(self.cfg, self.tc, self.n_agents, self.n_pods))
-        self._history: list[Dict[str, float]] = []
+            step_on_mesh, in_shardings=(self.state_shardings, batch_sharding),
+            out_shardings=(self.state_shardings, None), donate_argnums=0)
 
     def init(self, seed: int = 0) -> TrainState:
-        return init_train_state(jax.random.key(seed), self.cfg, self.tc,
-                                self.n_agents)
+        key = jax.random.key(seed)
+        if self.mesh is None:
+            return init_train_state(key, self.cfg, self.tc, self.n_agents)
+        return jax.jit(
+            lambda k: init_train_state(k, self.cfg, self.tc, self.n_agents),
+            out_shardings=self.state_shardings)(key)
 
     def run(self, state: TrainState, data: Iterator[Dict[str, np.ndarray]],
             steps: int) -> TrainState:
@@ -73,13 +109,18 @@ class Trainer:
                     t1 = time.perf_counter()
                     timer.tick()
                     with obs.span("train.metrics"):
-                        scalars = {k: float(np.asarray(v))
-                                   for k, v in metrics.items()
-                                   if np.asarray(v).ndim == 0}
+                        host = {k: np.asarray(v) for k, v in metrics.items()}
+                        scalars = {k: float(v) for k, v in host.items()
+                                   if v.ndim == 0}
                         t2 = time.perf_counter()
                         if self.sink is not None:
+                            # per-agent vectors (agent_loss) ride along as
+                            # lists; trajectory loaders read scalars only
+                            vectors = {k: v.tolist() for k, v in host.items()
+                                       if v.ndim == 1}
                             rec = dict(
-                                step=i, **scalars, **timer.counters(),
+                                step=i, **scalars, **vectors,
+                                **timer.counters(),
                                 phase_data_ms=round((t0 - t_step) * 1e3, 3),
                                 phase_step_ms=round((t1 - t0) * 1e3, 3),
                                 phase_metrics_ms=round((t2 - t1) * 1e3, 3))

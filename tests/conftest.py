@@ -5,5 +5,15 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+
+
+@pytest.fixture
+def pallas_interpret():
+    """Run the Pallas TPU kernels in Pallas' TPU interpret mode for this
+    test.  Off the TPU a kernel only runs when a test asks for this."""
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
+        yield

@@ -86,7 +86,7 @@ def test_expsum_tracks_exact():
 
 
 @pytest.mark.parametrize("mode", ["exact", "expsum"])
-def test_kernel_path_matches_jnp_path(mode):
+def test_kernel_path_matches_jnp_path(mode, pallas_interpret):
     gs = _grad_stream(6)
     p = _params()
     cfg = dict(alpha=0.3, beta=0.1, lam=0.2, T=5, memory_mode=mode, K=4)

@@ -1,5 +1,6 @@
 """Per-kernel allclose vs the pure-jnp oracle: shape x dtype sweeps +
-hypothesis property tests (interpret mode on CPU).
+hypothesis property tests, in Pallas' TPU interpret mode on the CPU (the
+``pallas_interpret`` fixture of conftest.py).
 
 ``hypothesis`` is an optional dev dependency (requirements-dev.txt): the
 sweep tests always run; the property tests only materialize when it is
@@ -17,6 +18,8 @@ except ImportError:          # property tests below are conditionally defined
 
 from repro.core import memory as fmem
 from repro.kernels import ops, ref
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 SHAPES = [(128,), (1000,), (64, 33), (7,), (3, 5, 11), (2048,), (1,)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -70,7 +73,9 @@ if hypothesis is not None:
         alpha=st.floats(0.0, 2.0),
         beta=st.floats(0.0, 2.0),
     )
-    @hypothesis.settings(max_examples=25, deadline=None)
+    @hypothesis.settings(
+        max_examples=25, deadline=None,
+        suppress_health_check=[hypothesis.HealthCheck.function_scoped_fixture])
     def test_exact_kernel_property(n, T, cursor, alpha, beta):
         rng = np.random.default_rng(n * 31 + T)
         g = jnp.asarray(rng.normal(size=n), jnp.float32)
