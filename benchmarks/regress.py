@@ -44,8 +44,7 @@ DEFAULT_STEPS = {"exp1": 150, "exp2": 40, "exp3": 400, "train": 12,
 #: dependent) — dropped from the train baseline; step_time_ms and the
 #: per-phase phase_*_ms columns stay and are compared as percentile bands
 #: like every other timing key
-TRAIN_VOLATILE_KEYS = ("wall_s", "throughput_items_per_s",
-                       "throughput_items_per_s_instant")
+TRAIN_VOLATILE_KEYS = ("wall_s", "throughput_items_per_s")
 
 
 def run_exp1(jsonl_path: str, seed: int, steps: int) -> None:

@@ -137,8 +137,8 @@ def mix_uniform_constrained(tree: Pytree, specs: Pytree, mesh) -> Pytree:
         out = jnp.broadcast_to(m[None], v.shape).astype(v.dtype)
         return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, sp))
 
-    return jax.tree.map(leaf, tree, specs,
-                        is_leaf=lambda x: False)
+    with trace_scope("consensus.mix_uniform_constrained"):
+        return jax.tree.map(leaf, tree, specs, is_leaf=lambda x: False)
 
 
 def pmean_shardmap(tree: Pytree, agent_axes, mesh) -> Pytree:

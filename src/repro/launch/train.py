@@ -73,11 +73,12 @@ def run_training(steps: int = 20, agents: int = 2, seq: int = 128,
     trajectories (the launch-train golden baseline relies on this).
 
     ``profile_dir`` turns on a programmatic ``jax.profiler`` capture over
-    steps ``[profile_start, profile_stop]`` — the ``trace_scope`` /
-    ``StepTraceAnnotation`` tags land in a real device trace there.
-    ``spans_out`` records host-side phase spans (``train.data`` /
-    ``train.device_step`` / ``train.metrics``) and writes them as a
-    Chrome trace-event file for Perfetto / ``repro.obs.report``.
+    steps ``[profile_start, profile_stop]`` — the ``trace_scope`` tags,
+    the ``StepTraceAnnotation`` and the host spans land in a real device
+    trace there.  ``spans_out`` also records the host-side phase spans
+    (``train.data`` / ``train.device_step`` / ``train.metrics`` /
+    ``train.log``) and writes them as a Chrome trace-event file for
+    Perfetto / ``repro.obs.report``.
     """
     from repro import obs
     from repro.data.synthetic import TokenPipeline, augment_modalities

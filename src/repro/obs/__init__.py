@@ -19,16 +19,16 @@ from repro.obs.regress import (MetricDiff, Tolerance, compare_to_baseline,
                                load_baseline, load_trajectories,
                                make_baseline, write_baseline)
 from repro.obs.spans import (PhaseStat, Span, SpanRecorder, aggregate,
-                             device_sync, get_recorder, set_recorder, span,
+                             gc_spans, get_recorder, set_recorder, span,
                              span_paths, to_chrome_trace, to_records)
-from repro.obs.timing import (ProfileWindow, StepTimer, annotate,
-                              step_annotation, trace_scope)
+from repro.obs.timing import (ProfileWindow, StepTimer, step_annotation,
+                              trace_scope)
 
 __all__ = [
     "JsonlSink", "MemorySink", "MetricDiff", "MetricsSink", "NullSink",
     "PhaseStat", "ProfileWindow", "Span", "SpanRecorder", "StepTimer",
-    "Tolerance", "aggregate", "annotate", "compare_to_baseline",
-    "consensus_error", "device_sync", "format_report", "frodo_step_metrics",
+    "Tolerance", "aggregate", "compare_to_baseline",
+    "consensus_error", "format_report", "frodo_step_metrics", "gc_spans",
     "get_recorder", "get_sink", "global_norm", "is_timing_metric",
     "load_baseline",
     "load_trajectories", "make_baseline", "read_jsonl", "record",

@@ -1,22 +1,19 @@
 """Hierarchical host-side span profiler: per-phase time breakdown.
 
-``span(name)`` marks one host-side phase of a driver loop.  With a
-:class:`SpanRecorder` installed (``with SpanRecorder() as rec:`` or
-``set_recorder``), entering/leaving the context pushes/pops a thread-local
-stack and appends one :class:`Span` row with monotonic-clock timestamps
-(``time.perf_counter_ns``).  With **no** recorder installed — the default —
-``span()`` returns a shared no-op singleton: nothing is allocated beyond
-the call itself, nothing is recorded, and nothing ever enters a traced or
-jitted function.  Spans are pure host instrumentation; the traced
-train-step jaxpr and the compiled scheduler decode program are byte-
-identical with a recorder installed (tests/test_spans.py pins this).
-
-``span(name, block=True)`` forces a best-effort device sync before the
-span closes, so the span times the work rather than the async dispatch.
-It is opt-in because the sync itself perturbs pipelining — only wrap
-regions whose caller accepts that (the drivers use it where they already
-block on the step's outputs).  The yielded handle additionally offers
+``span(name)`` marks one host-side phase of a host loop.  It always opens
+a ``jax.profiler.TraceAnnotation`` of the same name, so the phase lands on
+the device trace's clock whenever a profiler trace is running (about 1 us a
+span when none is).  With a :class:`SpanRecorder` installed as well
+(``with SpanRecorder() as rec:`` or ``set_recorder``), entering/leaving the
+context pushes/pops a thread-local stack and appends one :class:`Span` row
+with monotonic-clock timestamps (``time.perf_counter_ns``).  Nothing ever
+enters a traced or jitted function: the traced train-step jaxpr and the
+compiled scheduler decode program are byte-identical with a recorder
+installed (tests/test_spans.py pins this).  The yielded handle offers
 ``sync(tree)`` to block on concrete outputs *inside* the span.
+
+``gc_spans()`` puts Python's garbage collections on the same clock: while
+it is active, every collection is a ``gc.gen<N>`` annotation.
 
 Downstream consumers:
 
@@ -31,19 +28,22 @@ Downstream consumers:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterable, List, Optional, Sequence
+from typing import Any, Dict, Iterator, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 __all__ = [
     "Span", "SpanRecorder", "PhaseStat", "span", "set_recorder",
     "get_recorder", "aggregate", "span_paths", "to_chrome_trace",
-    "to_records", "device_sync",
+    "to_records", "gc_spans",
 ]
 
 
@@ -59,17 +59,6 @@ class Span:
     parent: int
     tid: int
     args: Optional[Dict[str, Any]] = None
-
-
-def device_sync() -> None:
-    """Best-effort wait for outstanding device work (used by
-    ``span(..., block=True)``).  Never raises — profiling must not take
-    the driver down on a jax build without the API."""
-    try:
-        import jax
-        jax.effects_barrier()
-    except Exception:                                    # pragma: no cover
-        pass
 
 
 class SpanRecorder:
@@ -170,65 +159,63 @@ def get_recorder() -> Optional[SpanRecorder]:
     return _RECORDER
 
 
-class _NoopSpan:
-    """Shared do-nothing span handle — the disabled path allocates nothing
-    and is safe to nest/reuse (it carries no state)."""
-    __slots__ = ()
+class _Span:
+    """One host phase: a profiler annotation, plus a recorder row when a
+    recorder was installed at the call."""
+    __slots__ = ("_name", "_rec", "_args", "_ann", "_idx")
 
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-    def sync(self, tree: Any) -> Any:
-        return tree
-
-
-_NOOP = _NoopSpan()
-
-
-class _LiveSpan:
-    __slots__ = ("_rec", "_name", "_block", "_args", "_idx")
-
-    def __init__(self, rec: SpanRecorder, name: str, block: bool,
+    def __init__(self, name: str, rec: Optional[SpanRecorder],
                  args: Optional[Dict[str, Any]]) -> None:
-        self._rec = rec
         self._name = name
-        self._block = block
+        self._rec = rec
         self._args = args
+        self._ann = None
         self._idx = -1
 
-    def __enter__(self) -> "_LiveSpan":
-        self._idx = self._rec.begin(self._name, self._args)
+    def __enter__(self) -> "_Span":
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._idx = self._rec.begin(self._name, self._args)
         return self
 
     def __exit__(self, *exc) -> bool:
-        if self._block:
-            device_sync()
-        self._rec.end(self._idx)
+        if self._rec is not None:
+            self._rec.end(self._idx)
+        self._ann.__exit__(*exc)
         return False
 
     def sync(self, tree: Any) -> Any:
         """Block on concrete outputs so the wait lands inside this span."""
-        try:
-            import jax
-            return jax.block_until_ready(tree)
-        except Exception:                                # pragma: no cover
-            return tree
+        return jax.block_until_ready(tree)
 
 
-def span(name: str, block: bool = False, **args: Any):
-    """Context manager marking one host-side phase.
+def span(name: str, **args: Any) -> _Span:
+    """Context manager marking one host-side phase: a profiler annotation
+    named ``name``, recorded too when a recorder is installed; ``**args``
+    become the recorded span's Chrome-trace args (e.g. ``step=i``)."""
+    return _Span(name, _RECORDER, args or None)
 
-    No-op (shared singleton, nothing recorded) unless a recorder is
-    installed.  ``block=True`` device-syncs at close; ``**args`` become
-    the span's Chrome-trace args (e.g. ``step=i``).
-    """
-    rec = _RECORDER
-    if rec is None:
-        return _NOOP
-    return _LiveSpan(rec, name, block, args or None)
+
+@contextlib.contextmanager
+def gc_spans() -> Iterator[None]:
+    """While active, each garbage collection is a ``gc.gen<N>`` profiler
+    annotation, from the collector's ``start`` to its ``stop``."""
+    open_ = []
+
+    def hook(phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation(f"gc.gen{info['generation']}")
+            ann.__enter__()
+            open_.append(ann)
+        elif open_:
+            open_.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(hook)
 
 
 # ------------------------------------------------------------- aggregation

@@ -5,11 +5,10 @@ drivers share: call ``tick()`` once per completed step (AFTER blocking on
 the step's outputs — an async dispatch that hasn't materialised yet would
 time the enqueue, not the work) and read ``step_time_ms`` / throughput.
 
-``annotate`` wraps host-side regions in ``jax.profiler.TraceAnnotation`` so
-they show up as named spans in a captured trace; ``trace_scope`` is the
-in-jit equivalent (``jax.named_scope``) used around the Pallas kernel path
-and the consensus collectives.  Both degrade to no-ops on jax builds that
-lack the API — telemetry must never take the training loop down.
+Host-side regions go on a captured trace through ``obs.span``
+(``repro.obs.spans``); ``trace_scope`` is the in-jit equivalent
+(``jax.named_scope``) around the train step's forward/backward, the FrODO
+update, the Pallas kernel path and the consensus mixes.
 """
 from __future__ import annotations
 
@@ -18,17 +17,6 @@ import time
 from typing import Dict, Iterator, Optional
 
 import jax
-
-
-@contextlib.contextmanager
-def annotate(name: str, **kwargs) -> Iterator[None]:
-    """Host-side trace span (visible in TensorBoard / perfetto captures)."""
-    try:
-        ctx = jax.profiler.TraceAnnotation(name, **kwargs)
-    except Exception:                                    # pragma: no cover
-        ctx = contextlib.nullcontext()
-    with ctx:
-        yield
 
 
 @contextlib.contextmanager
@@ -89,19 +77,11 @@ class StepTimer:
 
     @property
     def items_per_s(self) -> float:
-        """Throughput off the EMA step time — the quotable number.  The
-        instantaneous value jitters with scheduler noise and GC pauses;
-        see ``items_per_s_instant`` for the raw per-step figure."""
+        """Throughput off the EMA step time: the per-step value jitters
+        with scheduler noise and GC pauses."""
         if not self.items_per_step or self.ema_step_time_ms <= 0:
             return 0.0
         return self.items_per_step / (self.ema_step_time_ms * 1e-3)
-
-    @property
-    def items_per_s_instant(self) -> float:
-        """Throughput off this step's wall time alone (noisy)."""
-        if not self.items_per_step or self.step_time_ms <= 0:
-            return 0.0
-        return self.items_per_step / (self.step_time_ms * 1e-3)
 
     def counters(self) -> Dict[str, float]:
         """The standard keys trainers merge into each metrics record."""
@@ -109,8 +89,6 @@ class StepTimer:
                "wall_s": round(self.wall_s, 3)}
         if self.items_per_step:
             out["throughput_items_per_s"] = round(self.items_per_s, 1)
-            out["throughput_items_per_s_instant"] = round(
-                self.items_per_s_instant, 1)
         return out
 
 

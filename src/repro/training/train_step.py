@@ -26,6 +26,7 @@ from repro.core import baselines
 from repro.distributed import sharding as SH
 from repro.models import transformer as T
 from repro.obs import metrics as obs_metrics
+from repro.obs.timing import trace_scope
 from repro.training.loss import (cross_entropy, chunked_cross_entropy,
                                  clip_by_global_norm)
 
@@ -305,37 +306,41 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, n_agents: int,
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         grad_fn = agent_grad_fn
-        if n_agents == 1:
-            sq = jax.tree.map(lambda x: x[0], (state.params, batch))
-            (loss, metrics), grads = grad_fn(*sq)
-            loss = loss[None]
-            metrics = jax.tree.map(lambda x: x[None], metrics)
-            grads = jax.tree.map(lambda x: x[None], grads)
-        else:
-            (loss, metrics), grads = jax.vmap(grad_fn)(state.params, batch)
+        with trace_scope("train.fwd_bwd"):
+            if n_agents == 1:
+                sq = jax.tree.map(lambda x: x[0], (state.params, batch))
+                (loss, metrics), grads = grad_fn(*sq)
+                loss = loss[None]
+                metrics = jax.tree.map(lambda x: x[None], metrics)
+                grads = jax.tree.map(lambda x: x[None], grads)
+            else:
+                (loss, metrics), grads = jax.vmap(grad_fn)(state.params,
+                                                           batch)
 
-        if tc.grad_clip > 0:
-            grads, gnorm = clip_by_global_norm(grads, tc.grad_clip *
-                                               np.sqrt(n_agents))
-        else:
-            gnorm = jnp.float32(0)
+        with trace_scope("frodo.update"):
+            if tc.grad_clip > 0:
+                grads, gnorm = clip_by_global_norm(grads, tc.grad_clip *
+                                                   np.sqrt(n_agents))
+            else:
+                gnorm = jnp.float32(0)
 
-        if faults is not None:
-            # stragglers / crashed agents: gradient discarded and update
-            # withheld for the step (state moves only via consensus)
-            u_t = fault_u[jnp.mod(state.step, fault_u.shape[0])]
+            if faults is not None:
+                # stragglers / crashed agents: gradient discarded and update
+                # withheld for the step (state moves only via consensus)
+                u_t = fault_u[jnp.mod(state.step, fault_u.shape[0])]
 
-            def agent_mask(t):
-                return jax.tree.map(
-                    lambda v: v * u_t.reshape(
-                        (n_agents,) + (1,) * (v.ndim - 1)).astype(v.dtype), t)
+                def agent_mask(t):
+                    return jax.tree.map(
+                        lambda v: v * u_t.reshape(
+                            (n_agents,) + (1,) * (v.ndim - 1)
+                        ).astype(v.dtype), t)
 
-            grads = agent_mask(grads)
+                grads = agent_mask(grads)
 
-        delta, opt_state = opt.update(grads, state.opt_state, state.params)
-        if faults is not None:
-            delta = agent_mask(delta)
-        params = apply_updates(state.params, delta)
+            delta, opt_state = opt.update(grads, state.opt_state, state.params)
+            if faults is not None:
+                delta = agent_mask(delta)
+            params = apply_updates(state.params, delta)
         pre_mix = params
 
         # stage 3: consensus over the agent dim

@@ -6,6 +6,13 @@ host-side step-timing counters and drained into ``sink`` (any
 ``obs.MetricsSink``).  ``metrics_file`` keeps the legacy end-of-run JSON
 history; ``sink`` is the per-step JSONL/streaming path.
 
+Host spans (``obs.span``, on a profiler trace's clock and in an installed
+``SpanRecorder``): each step is ``train.step`` over ``train.data`` (next
+batch), ``train.device_step`` (dispatch, inside the profiler's ``train``
+step annotation), ``train.metrics`` (the metrics' device-to-host fetch) and
+``train.log`` (sink, history, print); a checkpoint is ``checkpoint_save``.
+While ``run`` runs, each garbage collection is a ``gc.gen<N>`` annotation.
+
 The step donates the state it is given: a caller keeps only the state that
 ``run`` (or the step) returns.  Given a ``mesh`` with a ``data`` axis (see
 ``launch/mesh.py:make_host_mesh``), the agent axis of the state and the
@@ -90,54 +97,59 @@ class Trainer:
         timer = obs.StepTimer(items_per_step=self.tokens_per_step)
         prof = obs.ProfileWindow(self.profile_dir, self.profile_start,
                                  self.profile_stop)
-        try:
-            for i in range(steps):
-                prof.maybe_start(i)
-                t_step = time.perf_counter()
-                with obs.span("train.step", step=i):
-                    with obs.span("train.data"):
-                        batch = next(data)
-                    t0 = time.perf_counter()
-                    with obs.step_annotation("train", step=i), \
-                            obs.span("train.device_step"):
-                        state, metrics = self.step_fn(state, batch)
-                        if (self.sink is not None
-                                or obs.get_recorder() is not None):
-                            # block so the timer (and the span) measures
-                            # the step, not the dispatch
-                            jax.block_until_ready(metrics)
-                    t1 = time.perf_counter()
-                    timer.tick()
-                    with obs.span("train.metrics"):
-                        host = {k: np.asarray(v) for k, v in metrics.items()}
-                        scalars = {k: float(v) for k, v in host.items()
-                                   if v.ndim == 0}
+        with obs.gc_spans():
+            try:
+                for i in range(steps):
+                    prof.maybe_start(i)
+                    t_step = time.perf_counter()
+                    with obs.span("train.step", step=i):
+                        with obs.span("train.data"):
+                            batch = next(data)
+                        t0 = time.perf_counter()
+                        with obs.step_annotation("train", step=i), \
+                                obs.span("train.device_step"):
+                            state, metrics = self.step_fn(state, batch)
+                            if (self.sink is not None
+                                    or obs.get_recorder() is not None):
+                                # block so the timer (and the span) measures
+                                # the step, not the dispatch
+                                jax.block_until_ready(metrics)
+                        t1 = time.perf_counter()
+                        timer.tick()
+                        with obs.span("train.metrics"):
+                            host = {k: np.asarray(v)
+                                    for k, v in metrics.items()}
+                            scalars = {k: float(v) for k, v in host.items()
+                                       if v.ndim == 0}
                         t2 = time.perf_counter()
-                        if self.sink is not None:
-                            # per-agent vectors (agent_loss) ride along as
-                            # lists; trajectory loaders read scalars only
-                            vectors = {k: v.tolist() for k, v in host.items()
-                                       if v.ndim == 1}
-                            rec = dict(
-                                step=i, **scalars, **vectors,
-                                **timer.counters(),
-                                phase_data_ms=round((t0 - t_step) * 1e3, 3),
-                                phase_step_ms=round((t1 - t0) * 1e3, 3),
-                                phase_metrics_ms=round((t2 - t1) * 1e3, 3))
-                            self.sink.write(rec)
-                if i % self.log_every == 0 or i == steps - 1:
-                    m = dict(scalars)
-                    m.update(step=i, wall=round(timer.wall_s, 2))
-                    self._history.append(m)
-                    print(json.dumps(m), flush=True)
-                if self.ckpt_every and (i + 1) % self.ckpt_every == 0:
-                    with obs.annotate("checkpoint_save"):
-                        ckpt.save(
-                            os.path.join(self.ckpt_dir, f"step{i+1}.npz"),
-                            state.params, {"step": i + 1})
-                prof.maybe_stop(i)
-        finally:
-            prof.close()
+                        with obs.span("train.log"):
+                            if self.sink is not None:
+                                # per-agent vectors (agent_loss) ride along as
+                                # lists; trajectory loaders read scalars only
+                                vectors = {k: v.tolist()
+                                           for k, v in host.items()
+                                           if v.ndim == 1}
+                                rec = dict(
+                                    step=i, **scalars, **vectors,
+                                    **timer.counters(),
+                                    phase_data_ms=round(
+                                        (t0 - t_step) * 1e3, 3),
+                                    phase_step_ms=round((t1 - t0) * 1e3, 3),
+                                    phase_metrics_ms=round((t2 - t1) * 1e3, 3))
+                                self.sink.write(rec)
+                            if i % self.log_every == 0 or i == steps - 1:
+                                m = dict(scalars)
+                                m.update(step=i, wall=round(timer.wall_s, 2))
+                                self._history.append(m)
+                                print(json.dumps(m), flush=True)
+                    if self.ckpt_every and (i + 1) % self.ckpt_every == 0:
+                        with obs.span("checkpoint_save"):
+                            ckpt.save(
+                                os.path.join(self.ckpt_dir, f"step{i+1}.npz"),
+                                state.params, {"step": i + 1})
+                    prof.maybe_stop(i)
+            finally:
+                prof.close()
         if self.metrics_file:
             os.makedirs(os.path.dirname(self.metrics_file) or ".",
                         exist_ok=True)

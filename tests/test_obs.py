@@ -127,8 +127,7 @@ def test_step_timer_counters():
     t = OT.StepTimer(items_per_step=10.0)
     assert t.tick() >= 0.0
     c1 = t.counters()
-    assert set(c1) == {"step_time_ms", "wall_s", "throughput_items_per_s",
-                       "throughput_items_per_s_instant"}
+    assert set(c1) == {"step_time_ms", "wall_s", "throughput_items_per_s"}
     assert c1["step_time_ms"] >= 0.0
     t2 = OT.StepTimer()
     t2.tick()
@@ -137,25 +136,22 @@ def test_step_timer_counters():
 
 def test_step_timer_throughput_quotes_ema():
     """The headline items/s comes off the EMA step time (stable under
-    one-off stalls); the raw per-step figure stays available as
-    ``items_per_s_instant``."""
+    one-off stalls), not off the last step's time."""
     t = OT.StepTimer(items_per_step=100.0, ema=0.9)
     t.tick()
     # inject known step times instead of sleeping
     t.step_time_ms, t.ema_step_time_ms = 50.0, 10.0
     assert t.items_per_s == pytest.approx(100.0 / (10.0 * 1e-3))
-    assert t.items_per_s_instant == pytest.approx(100.0 / (50.0 * 1e-3))
     c = t.counters()
     assert c["throughput_items_per_s"] == pytest.approx(10000.0, abs=0.1)
-    assert c["throughput_items_per_s_instant"] == pytest.approx(2000.0,
-                                                                abs=0.1)
+    assert c["step_time_ms"] == pytest.approx(50.0)
     # first tick seeds the EMA with the first measurement
     t3 = OT.StepTimer(items_per_step=1.0)
     first = t3.tick()
     assert t3.ema_step_time_ms == pytest.approx(first)
     # zero-state edge: no division by zero before any tick
     t4 = OT.StepTimer(items_per_step=1.0)
-    assert t4.items_per_s == 0.0 and t4.items_per_s_instant == 0.0
+    assert t4.items_per_s == 0.0
 
 
 # --------------------------------------------------- jit-safe computations

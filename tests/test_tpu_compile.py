@@ -1,12 +1,15 @@
 """Compiles for a described TPU v5e (no chip attached): the fused FrODO
 kernels at h2o-danube-1.8b leaf shapes, and the one-chip train step at the
 cut that chip_smoke.py runs.  Nothing executes; Mosaic and XLA's TPU
-compiler refuse here what the chip would refuse.
+compiler refuse here what the chip would refuse.  The compiled step's
+operations carry the step's name scopes, which a device trace reports
+beside each operation.
 
 Every chip compile test lives in this file, and the topology is described
 only inside the fixture below, so that one pytest worker loads the TPU
 library."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,12 @@ HEADROOM_BYTES = 2 * 2 ** 30
 
 # agent-stacked leaves at the cut: an MLP matrix, the embedding, a norm
 LEAVES = [(2, 2560, 6912), (2, 32000, 2560), (2, 2560)]
+
+# a name scope inside an op_name path such as
+# "jit(train_step)/vmap(transpose(jvp(train.fwd_bwd)))/dot_general"
+SCOPE = re.compile(r"(?<=[/(])[A-Za-z_]\w*(?:\.\w+)+(?=[/)]|$)")
+HEADER = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
 
 
 @pytest.fixture(scope="module")
@@ -73,19 +82,32 @@ def test_expsum_kernel_compiles(one_chip, shape, K, acc_dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+_STEPS = {}
+
+
+def _compiled_step(one_chip, use_kernel):
+    """The step chip_smoke.py runs (metrics on, state donated), compiled
+    once per test module; returns (compiled, abstract state)."""
+    if use_kernel not in _STEPS:
+        trainer = build_trainer(**CHIP_TRAIN, use_kernel=use_kernel,
+                                collect_metrics=True)
+        state = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                             abstract_train_state(trainer.cfg, trainer.tc,
+                                                  trainer.n_agents))
+        A, B, S = (CHIP_TRAIN[k] for k in ("agents", "batch_per_agent",
+                                           "seq"))
+        batch = {k: _sds((A, B, S), jnp.int32, one_chip)
+                 for k in ("tokens", "labels")}
+        _STEPS[use_kernel] = (trainer.step_fn.lower(state, batch).compile(),
+                              state)
+    return _STEPS[use_kernel]
+
+
 @pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "fused"])
 def test_one_chip_train_step_fits(one_chip, use_kernel):
     """The step chip_smoke.py runs (metrics on, state donated) leaves at
     least HEADROOM_BYTES of the chip free."""
-    trainer = build_trainer(**CHIP_TRAIN, use_kernel=use_kernel,
-                            collect_metrics=True)
-    state = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
-                         abstract_train_state(trainer.cfg, trainer.tc,
-                                              trainer.n_agents))
-    A, B, S = (CHIP_TRAIN[k] for k in ("agents", "batch_per_agent", "seq"))
-    batch = {k: _sds((A, B, S), jnp.int32, one_chip)
-             for k in ("tokens", "labels")}
-    compiled = trainer.step_fn.lower(state, batch).compile()
+    compiled, state = _compiled_step(one_chip, use_kernel)
     mem = compiled.memory_analysis()
     state_bytes = sum(s.size * s.dtype.itemsize
                       for s in jax.tree.leaves(state))
@@ -94,3 +116,42 @@ def test_one_chip_train_step_fits(one_chip, use_kernel):
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used <= HBM_BYTES - HEADROOM_BYTES, used / 2 ** 30
     assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+
+
+def hlo_scopes(hlo: str) -> dict:
+    """``{computation: [(instruction, outermost scope or None, called
+    fusion computation or None)]}`` of a compiled module's text."""
+    comps, comp = {}, None
+    for line in hlo.splitlines():
+        head = HEADER.match(line)
+        if head:
+            comp = comps.setdefault(head.group(1), [])
+            continue
+        inst = INSTRUCTION.match(line)
+        if inst is None or comp is None:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        scope = SCOPE.search(op.group(1)) if op else None
+        calls = re.search(r" fusion\(.*calls=%([^,\s]+)", line)
+        comp.append((inst.group(1), scope and scope.group(0),
+                     calls and calls.group(1)))
+    return comps
+
+
+def test_one_chip_step_operations_carry_the_step_scopes(one_chip):
+    """The forward/backward, the FrODO update and the mix each name at
+    least one operation that the device runs (a fusion or an unfused op).
+    A fusion carries its root's op_name only, so a fusion whose fused
+    instructions come from several scopes counts wholly to its root's:
+    those are printed."""
+    comps = hlo_scopes(_compiled_step(one_chip, False)[0].as_text())
+    fused = {c for insts in comps.values() for _, _, c in insts if c}
+    run = {scope for name, insts in comps.items() if name not in fused
+           for _, scope, _ in insts}
+    for scope in ("train.fwd_bwd", "frodo.update", "consensus.mix_uniform"):
+        assert scope in run, (scope, sorted(s for s in run if s))
+    for insts in comps.values():
+        for name, scope, calls in insts:
+            inner = {s for _, s, _ in comps.get(calls, ()) if s}
+            if len(inner) > 1:
+                print(f"{name}: counted to {scope}, fuses {sorted(inner)}")

@@ -1,7 +1,9 @@
 """Trainer placement and entry-point plumbing: donated state, agents on a
-mesh, the depth cut at published widths, the compile-cache location, and
+mesh, the depth cut at published widths, the compile-cache location,
 kernels that refuse to run off the TPU unless a test asks for interpret
-mode."""
+mode, and the trainer's host spans on a profiler trace."""
+import gc
+import glob
 import json
 import os
 import subprocess
@@ -35,6 +37,41 @@ def test_step_donates_the_state():
     new_state, _ = trainer.step_fn(state, _batch(2, 16))
     assert leaf.is_deleted()
     assert not jax.tree.leaves(new_state.params)[0].is_deleted()
+
+
+def test_trainer_spans_land_on_the_profiler_trace(tmp_path):
+    """A profiler trace of ``Trainer.run`` carries the step annotation, the
+    trainer's phase spans and a collection inside the batch fetch, all on
+    the host plane; the collection hook is gone after the run."""
+    from jax.profiler import ProfileData
+
+    trainer = build_trainer(**SMALL)
+    state = trainer.init(seed=0)
+
+    def data():
+        for i in range(3):
+            if i == 1:
+                gc.collect()
+            yield _batch(2, 16, seed=i)
+
+    hooks = list(gc.callbacks)
+    with jax.profiler.trace(str(tmp_path)):
+        trainer.run(state, data(), 3)
+    assert gc.callbacks == hooks
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(e.name, e.start_ns, e.end_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    count = {}
+    for name, _, _ in events:
+        count[name] = count.get(name, 0) + 1
+    for name in ("train", "train.step", "train.data", "train.device_step",
+                 "train.metrics", "train.log"):
+        assert count.get(name) == 3, (name, count.get(name))
+    fetches = [(s, e) for n, s, e in events if n == "train.data"]
+    assert any(s <= g0 and g1 <= e for n, g0, g1 in events
+               if n == "gc.gen2" for s, e in fetches)
 
 
 def test_mesh_trainer_matches_plain_trainer():
