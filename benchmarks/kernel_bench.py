@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import memory as fmem
+from repro.kernels import frodo_update as kfu
 from repro.kernels import ops, ref
 from repro.obs.spans import span
 
@@ -58,16 +59,16 @@ def rows(seed=0):
         out.append((f"frodo_exact_jnp_n{n}", us_ref, f"hbm_bytes={unfused}"))
         out.append((f"frodo_exact_pallas_n{n}(interp)", us_ker,
                     f"hbm_bytes={fused}"))
-        acc = jnp.asarray(rng.normal(size=(K, n)), jnp.float32)
+        shape = (n // 1024, 1024)
+        g2 = g.reshape(shape)
+        acc = jnp.asarray(rng.normal(size=(K,) + shape), jnp.float32)
         rates, coeffs = fmem.fit_expsum(90, 0.15, K)
-        rates = jnp.asarray(rates, jnp.float32)
-        coeffs = jnp.asarray(coeffs, jnp.float32)
-        jr2 = jax.jit(lambda g, a: ref.frodo_expsum_update_ref(
-            g, a, rates, coeffs, 0.8, 0.35))
-        us_ref2 = _time(jr2, g, acc, name=f"expsum_jnp_n{n}")
-        us_ker2 = _time(lambda g, a: ops.frodo_expsum_update(
-            g, a, rates, coeffs, 0.8, 0.35), g, acc,
-            name=f"expsum_pallas_n{n}")
+        jr2 = jax.jit(lambda g, a, p: ref.frodo_expsum_apply_ref(
+            g, a, p, 1.0, rates, coeffs, 0.8, 0.35))
+        us_ref2 = _time(jr2, g2, acc, g2, name=f"expsum_jnp_n{n}")
+        us_ker2 = _time(jax.jit(lambda g, a, p: kfu.expsum_apply(
+            g, a, p, jnp.float32(1), rates=rates, coeffs=coeffs, alpha=0.8,
+            beta=0.35)), g2, acc, g2, name=f"expsum_pallas_n{n}")
         fused, unfused = traffic_model(n, K=K)
         out.append((f"frodo_expsum_jnp_n{n}", us_ref2,
                     f"hbm_bytes={unfused}"))

@@ -10,12 +10,14 @@ the per-agent update
     x_i  <- x_i - alpha g_i - beta M_i
 
 with two memory representations (exact circular buffer / exponential-sum
-accumulators, see core.memory) and an optional fused Pallas kernel path for
-the update arithmetic (kernels/frodo_update.py).
+accumulators, see core.memory) and fused Pallas kernels
+(kernels/frodo_update.py): the exact mode's behind ``use_kernel``; the
+exp-sum mode's whenever it runs on a TPU, through ``Optimizer.apply``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -23,7 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import memory as fmem
+from repro.kernels import frodo_update as kfu
 from repro.obs import metrics as obs_metrics
+from repro.obs.timing import trace_scope
 
 Params = Any
 Grads = Any
@@ -36,9 +40,17 @@ METRIC_NAMES = ("grad_norm", "memory_norm", "update_norm")
 
 class Optimizer(NamedTuple):
     """Optax-style pair.  ``update`` returns (delta, new_state); the caller
-    applies ``params = params + delta``."""
+    applies ``params = params + delta``.
+
+    ``apply``, where the optimizer has one, is the update and its
+    application in one call: ``apply(grads, state, params, scale, mesh,
+    specs)`` returns (new params, new state) as ``update`` on
+    ``scale * grads`` and ``apply_updates`` would (``scale`` None is 1;
+    ``mesh`` and ``specs``, the params' PartitionSpecs, where the step runs
+    on a mesh)."""
     init: Callable[[Params], State]
     update: Callable[[Grads, State, Optional[Params]], tuple[Any, State]]
+    apply: Optional[Callable[..., tuple[Params, State]]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,7 +62,10 @@ class FrodoConfig:
     memory_mode: str = "exact"  # "exact" (paper) | "expsum" (beyond-paper)
     K: int = 8                  # number of exponentials for expsum mode
     exponent_scale: float = 1.0
-    use_kernel: bool = False    # route update arithmetic through Pallas ops
+    # exact mode: route the update arithmetic through the Pallas kernel.  The
+    # exp-sum mode ignores it: on a TPU its ``apply`` is always the fused
+    # kernel (kernels/frodo_update.py:expsum_apply), elsewhere always jnp
+    use_kernel: bool = False
     acc_dtype: str = "float32"  # expsum accumulator dtype (bf16 halves state)
     pad_T: int = 0              # buffer size override (weights zero beyond T)
     collect_metrics: bool = False  # aux ||g||/||M||/||delta|| in state["metrics"]
@@ -134,34 +149,91 @@ def _frodo_expsum(cfg: FrodoConfig) -> Optimizer:
             state["metrics"] = obs_metrics.zeros_like_metrics(METRIC_NAMES)
         return state
 
+    def leaf(g, a):
+        M = fmem.expsum_memory_term(a, coeffs)
+        delta = -(cfg.alpha * g + cfg.beta * M.astype(g.dtype))
+        return delta, fmem.expsum_push(a, rates, g), M
+
     def update(grads: Grads, state: State, params: Optional[Params] = None):
-        collect = cfg.collect_metrics
-        if cfg.use_kernel:
-            from repro.kernels import ops as kops
-            def leaf(g, a):
-                delta, newa = kops.frodo_expsum_update(
-                    g, a, rates, coeffs, cfg.alpha, cfg.beta)
-                M = fmem.expsum_memory_term(a, coeffs) if collect else None
-                return delta, newa, M
-        else:
-            def leaf(g, a):
-                M = fmem.expsum_memory_term(a, coeffs)
-                delta = -(cfg.alpha * g + cfg.beta * M.astype(g.dtype))
-                return delta, fmem.expsum_push(a, rates, g), \
-                    (M if collect else None)
         flat_g, treedef = jax.tree.flatten(grads)
         flat_a = treedef.flatten_up_to(state["acc"])
         out = [leaf(g, a) for g, a in zip(flat_g, flat_a)]
         delta = treedef.unflatten([o[0] for o in out])
         acc = treedef.unflatten([o[1] for o in out])
         new_state = {"step": state["step"] + 1, "acc": acc}
-        if collect:
+        if cfg.collect_metrics:
             Ms = treedef.unflatten([o[2] for o in out])
             new_state["metrics"] = obs_metrics.frodo_step_metrics(
                 grads, Ms, delta)
         return delta, new_state
 
-    return Optimizer(init, update)
+    def leaf_jnp(g, a, p, scale):
+        if scale is not None:
+            g = (g * scale).astype(g.dtype)
+        delta, a, _ = leaf(g, a)
+        return a, p + delta.astype(p.dtype)
+
+    fused = functools.partial(
+        kfu.expsum_apply, rates=tuple(map(float, rates_np)),
+        coeffs=tuple(map(float, coeffs_np)), alpha=cfg.alpha, beta=cfg.beta)
+    logged = []
+
+    def apply(grads: Grads, state: State, params: Params, scale=None,
+              mesh=None, specs=None):
+        """One call for update and apply.  On a TPU (or under Pallas' TPU
+        interpret mode) every leaf that the kernel tiles in its device
+        layout is one ``expsum_apply`` pass, per shard under a mesh; the
+        rest, and every leaf elsewhere, take the jnp update."""
+        if cfg.collect_metrics:
+            raise ValueError("apply keeps no metrics: use update")
+        device = (mesh.devices.flat[0] if mesh is not None
+                  else kfu.default_device())
+        kernel = device.platform == "tpu" or kfu.interpret_forced()
+        flat_g, treedef = jax.tree.flatten(grads)
+        flat_a = treedef.flatten_up_to(state["acc"])
+        flat_p = treedef.flatten_up_to(params)
+        flat_s = (treedef.flatten_up_to(specs) if mesh is not None
+                  else [None] * len(flat_g))
+        orders = [kfu.expsum_order(
+            p.shape if sp is None else jax.sharding.NamedSharding(
+                mesh, sp).shard_shape(p.shape),
+            p.dtype, a.dtype, cfg.K, device) if kernel else None
+            for p, a, sp in zip(flat_p, flat_a, flat_s)]
+        if not logged:
+            logged.append(True)
+            _log_split(flat_p, orders, device)
+        s = jnp.float32(1) if scale is None else scale
+        out = []
+        for g, a, p, sp, order in zip(flat_g, flat_a, flat_p, flat_s,
+                                      orders):
+            if order is None:
+                out.append(leaf_jnp(g, a, p, scale))
+                continue
+            call = functools.partial(fused, order=order)
+            if mesh is not None:
+                P = jax.sharding.PartitionSpec
+                acc_spec = P(None, *sp)
+                call = jax.shard_map(call, mesh=mesh,
+                                     in_specs=(sp, acc_spec, sp, P()),
+                                     out_specs=(acc_spec, sp),
+                                     check_vma=False)
+            with trace_scope("pallas.frodo_expsum_apply"):
+                out.append(call(g, a, p, s))
+        acc = treedef.unflatten([o[0] for o in out])
+        new_params = treedef.unflatten([o[1] for o in out])
+        return new_params, {"step": state["step"] + 1, "acc": acc}
+
+    return Optimizer(init, update, apply)
+
+
+def _log_split(flat_p, orders, device) -> None:
+    """One record each for the parameters on the fused path and on jnp."""
+    for name, on in (("frodo.fused_params", True),
+                     ("frodo.jnp_params", False)):
+        sizes = [int(np.prod(p.shape)) for p, o in zip(flat_p, orders)
+                 if (o is not None) == on]
+        obs_metrics.record(name, sum(sizes), leaves=len(sizes),
+                           platform=device.platform)
 
 
 # ------------------------------------------------------------------ helpers

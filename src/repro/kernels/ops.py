@@ -1,4 +1,6 @@
-"""jit'd wrappers: arbitrary-shape params -> 2-D tiles -> Pallas kernels."""
+"""jit'd wrapper of the exact-mode kernel: arbitrary-shape params -> 2-D
+tiles -> Pallas kernel.  (The exp-sum kernel, ``frodo_update.expsum_apply``,
+takes each leaf in its own layout and needs no wrapper.)"""
 from __future__ import annotations
 
 from functools import partial
@@ -47,18 +49,3 @@ def frodo_update(g: jax.Array, hist: jax.Array, cursor: jax.Array,
         delta = _from_2d(delta2, g.shape, n)
         new_hist = fmem.exact_push(hist, cursor, g)
     return delta, new_hist
-
-
-@partial(jax.jit, static_argnames=("alpha", "beta"))
-def frodo_expsum_update(g: jax.Array, acc: jax.Array, rates: jax.Array,
-                        coeffs: jax.Array, alpha: float, beta: float):
-    """Fused exp-sum FrODO update.  acc: (K, ...).  Returns (delta, new_acc)."""
-    with trace_scope("pallas.frodo_expsum_update"):
-        g2, n = _to_2d(g)
-        a2 = jax.vmap(lambda a: _to_2d(a)[0])(acc)
-        delta2, newacc2 = K.expsum_update_2d(g2, a2, rates, coeffs, alpha,
-                                             beta)
-        delta = _from_2d(delta2, g.shape, n)
-        new_acc = jax.vmap(lambda a, ref: _from_2d(a, ref.shape, n))(
-            newacc2, acc)
-    return delta, new_acc

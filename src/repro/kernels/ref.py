@@ -18,10 +18,15 @@ def frodo_update_ref(g: jax.Array, hist: jax.Array, cursor: jax.Array,
     return delta, new_hist
 
 
-def frodo_expsum_update_ref(g: jax.Array, acc: jax.Array, rates: jax.Array,
-                            coeffs: jax.Array, alpha: float, beta: float):
-    """Exp-sum fused update.  acc: (K, ...).  Returns (delta, new_acc)."""
-    M = fmem.expsum_memory_term(acc, coeffs)
-    delta = -(alpha * g + beta * M.astype(g.dtype))
-    new_acc = fmem.expsum_push(acc, rates, g)
-    return delta, new_acc
+def frodo_expsum_apply_ref(g: jax.Array, acc: jax.Array, p: jax.Array,
+                           scale, rates, coeffs, alpha: float, beta: float):
+    """Exp-sum update and apply in float32, each output rounded once.
+    acc: (K, ...); rates, coeffs: (K,).  Returns (new_acc, new_p)."""
+    f32 = jnp.float32
+    g = jnp.asarray(scale, f32) * g.astype(f32)
+    a = acc.astype(f32)
+    M = jnp.tensordot(jnp.asarray(coeffs, f32), a, axes=(0, 0),
+                      precision="highest")
+    r = jnp.asarray(rates, f32).reshape((-1,) + (1,) * g.ndim)
+    new_p = p.astype(f32) - (alpha * g + beta * M)
+    return (r * (a + g[None])).astype(acc.dtype), new_p.astype(p.dtype)

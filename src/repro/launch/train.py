@@ -133,7 +133,8 @@ def main():
     ap.add_argument("--acc-dtype", default="float32",
                     choices=("float32", "bfloat16"))
     ap.add_argument("--use-kernel", action="store_true",
-                    help="fused Pallas update (needs a TPU)")
+                    help="exact mode: fused Pallas update (needs a TPU); "
+                         "the expsum mode's is fused on any TPU")
     ap.add_argument("--topology", default="complete")
     ap.add_argument("--consensus-interval", type=int, default=1)
     ap.add_argument("--force-devices", type=int, default=0)

@@ -70,7 +70,13 @@ def global_norm(tree) -> jax.Array:
                         for x in jax.tree.leaves(tree)))
 
 
+def clip_scale(norm: jax.Array, max_norm: float) -> jax.Array:
+    """The factor that brings a tree of global norm ``norm`` to at most
+    ``max_norm``."""
+    return jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
+
+
 def clip_by_global_norm(tree, max_norm: float):
     norm = global_norm(tree)
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
+    scale = clip_scale(norm, max_norm)
     return jax.tree.map(lambda x: (x * scale).astype(x.dtype), tree), norm

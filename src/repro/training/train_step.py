@@ -28,7 +28,8 @@ from repro.models import transformer as T
 from repro.obs import metrics as obs_metrics
 from repro.obs.timing import trace_scope
 from repro.training.loss import (cross_entropy, chunked_cross_entropy,
-                                 clip_by_global_norm)
+                                 clip_by_global_norm, clip_scale,
+                                 global_norm)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +43,7 @@ class TrainConfig:
     memory_mode: str = "expsum"          # expsum default at LLM scale
     K: int = 8
     acc_dtype: str = "float32"
-    use_kernel: bool = False
+    use_kernel: bool = False             # exact mode only (FrodoConfig)
     grad_clip: float = 1.0
     remat: object = True        # False | True("nothing") | "dots" | "dots_no_batch"
     microbatches: int = 1                # grad-accumulation steps per round
@@ -279,6 +280,10 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, n_agents: int,
                           for k, v in faults.counter_arrays().items()}
         fault_u = jnp.asarray(faults.update_mask, jnp.float32)
         fault_W_seq = jnp.asarray(faults.W_seq, jnp.float32)
+    # the optimizer's one-call update and apply, where it has one; masked
+    # updates (faults) and the optimizer's metrics take update + apply
+    fused_apply = (opt.apply is not None and faults is None
+                   and not tc.collect_metrics)
 
     def agent_grad_fn(params1, batch1):
         """Per-agent (loss, metrics), grads — microbatched grad accumulation
@@ -318,29 +323,47 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, n_agents: int,
                                                            batch)
 
         with trace_scope("frodo.update"):
-            if tc.grad_clip > 0:
-                grads, gnorm = clip_by_global_norm(grads, tc.grad_clip *
-                                                   np.sqrt(n_agents))
+            max_norm = tc.grad_clip * np.sqrt(n_agents)
+            if fused_apply:
+                # update and apply in one call: the clip's global norm is
+                # its own reduction, its scale goes into the update
+                if tc.grad_clip > 0:
+                    gnorm = global_norm(grads)
+                    scale = clip_scale(gnorm, max_norm)
+                else:
+                    gnorm, scale = jnp.float32(0), None
+                mesh = SH.current_mesh()
+                specs = None if mesh is None else param_specs(
+                    jax.eval_shape(lambda p: p, state.params),
+                    SH.current_rules() or {}, mesh, agent_stacked=True)
+                params, opt_state = opt.apply(grads, state.opt_state,
+                                              state.params, scale,
+                                              mesh=mesh, specs=specs)
             else:
-                gnorm = jnp.float32(0)
+                if tc.grad_clip > 0:
+                    grads, gnorm = clip_by_global_norm(grads, max_norm)
+                else:
+                    gnorm = jnp.float32(0)
 
-            if faults is not None:
-                # stragglers / crashed agents: gradient discarded and update
-                # withheld for the step (state moves only via consensus)
-                u_t = fault_u[jnp.mod(state.step, fault_u.shape[0])]
+                if faults is not None:
+                    # stragglers / crashed agents: gradient discarded and
+                    # update withheld for the step (state moves only via
+                    # consensus)
+                    u_t = fault_u[jnp.mod(state.step, fault_u.shape[0])]
 
-                def agent_mask(t):
-                    return jax.tree.map(
-                        lambda v: v * u_t.reshape(
-                            (n_agents,) + (1,) * (v.ndim - 1)
-                        ).astype(v.dtype), t)
+                    def agent_mask(t):
+                        return jax.tree.map(
+                            lambda v: v * u_t.reshape(
+                                (n_agents,) + (1,) * (v.ndim - 1)
+                            ).astype(v.dtype), t)
 
-                grads = agent_mask(grads)
+                    grads = agent_mask(grads)
 
-            delta, opt_state = opt.update(grads, state.opt_state, state.params)
-            if faults is not None:
-                delta = agent_mask(delta)
-            params = apply_updates(state.params, delta)
+                delta, opt_state = opt.update(grads, state.opt_state,
+                                              state.params)
+                if faults is not None:
+                    delta = agent_mask(delta)
+                params = apply_updates(state.params, delta)
         pre_mix = params
 
         # stage 3: consensus over the agent dim

@@ -87,11 +87,26 @@ def test_expsum_tracks_exact():
 
 @pytest.mark.parametrize("mode", ["exact", "expsum"])
 def test_kernel_path_matches_jnp_path(mode, pallas_interpret):
+    """Pallas' interpret mode: the exact mode's kernel (``use_kernel``) and
+    the exp-sum mode's fused ``apply`` against the jnp update."""
     gs = _grad_stream(6)
     p = _params()
     cfg = dict(alpha=0.3, beta=0.1, lam=0.2, T=5, memory_mode=mode, K=4)
+    if mode == "expsum":
+        # a matrix that the kernel tiles beside leaves that stay on jnp
+        w = jnp.asarray(np.random.default_rng(1).normal(size=(2, 32, 128)),
+                        jnp.float32)
+        p = dict(p, w=w)
+        gs = [dict(g, w=w * (i + 1) / 7) for i, g in enumerate(gs)]
     ref = _run_steps(frodo(FrodoConfig(**cfg)), p, gs)
-    ker = _run_steps(frodo(FrodoConfig(**cfg, use_kernel=True)), p, gs)
+    if mode == "exact":
+        ker = _run_steps(frodo(FrodoConfig(**cfg, use_kernel=True)), p, gs)
+    else:
+        opt = frodo(FrodoConfig(**cfg))
+        state, ker = opt.init(p), []
+        for g in gs:
+            p, state = opt.apply(g, state, p)
+            ker.append(p)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         a, b, rtol=1e-4, atol=1e-5), ref[-1], ker[-1])
 

@@ -1,9 +1,11 @@
 """Compiles for a described TPU v5e (no chip attached): the fused FrODO
-kernels at h2o-danube-1.8b leaf shapes, and the one-chip train step at the
-cut that chip_smoke.py runs.  Nothing executes; Mosaic and XLA's TPU
-compiler refuse here what the chip would refuse.  The compiled step's
-operations carry the step's name scopes, which a device trace reports
-beside each operation.
+kernels at h2o-danube-1.8b leaf shapes, the one-chip train step at the cut
+that chip_smoke.py runs, and the four-agent step over a 2x2 mesh.  Nothing
+executes; Mosaic and XLA's TPU compiler refuse here what the chip would
+refuse.  The compiled step's operations carry the step's name scopes,
+which a device trace reports beside each operation.  The steps are traced
+with the described chip as JAX's default device, so that the exp-sum
+update matches that chip's layouts, as it does on the chip itself.
 
 Every chip compile test lives in this file, and the topology is described
 only inside the fixture below, so that one pytest worker loads the TPU
@@ -13,10 +15,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.configs.h2o_danube_1_8b import CHIP_TRAIN
+from repro.configs.h2o_danube_1_8b import CHIP_TRAIN, FOUR_CHIP_TRAIN
+from repro.core import memory as fmem
+from repro.kernels import frodo_update as kfu
 from repro.kernels import ops
 from repro.launch.train import build_trainer
 from repro.training.train_step import abstract_train_state
@@ -26,6 +31,12 @@ HEADROOM_BYTES = 2 * 2 ** 30
 
 # agent-stacked leaves at the cut: an MLP matrix, the embedding, a norm
 LEAVES = [(2, 2560, 6912), (2, 32000, 2560), (2, 2560)]
+# the benchmark cell's exp-sum leaves (2 agents, 4 layers): MLP in and out,
+# a k/v projection flattened, the embedding, the head, and the attention
+# projections as the model stores them
+EXPSUM_LEAVES = [(2, 4, 2560, 6912), (2, 4, 6912, 2560), (2, 4, 2560, 640),
+                 (2, 32000, 2560), (2, 2560, 32000), (2, 4, 2560, 32, 80),
+                 (2, 4, 32, 80, 2560)]
 
 # a name scope inside an op_name path such as
 # "jit(train_step)/vmap(transpose(jvp(train.fwd_bwd)))/dot_general"
@@ -35,7 +46,7 @@ INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     prev_log = os.environ.get("TPU_LOG_DIR")
@@ -48,10 +59,20 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler installed
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev_cache)
     if prev_log is None:
         os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
+
+
+def _device(sharding):
+    device, = sharding.device_set
+    return device
 
 
 def _sds(shape, dtype, sharding):
@@ -72,25 +93,40 @@ def test_exact_kernel_compiles(one_chip, shape):
 
 
 @pytest.mark.parametrize("K,acc_dtype", [(4, jnp.bfloat16), (8, jnp.float32)])
-@pytest.mark.parametrize("shape", LEAVES)
+@pytest.mark.parametrize("shape", EXPSUM_LEAVES)
 def test_expsum_kernel_compiles(one_chip, shape, K, acc_dtype):
-    compiled = ops.frodo_expsum_update.lower(
+    """The one-pass exp-sum kernel at the cell's leaf shapes, each in the
+    chip's default layout: its operands reach the kernel through bitcasts
+    only (the layout-matching transposes), never a copy."""
+    device = _device(one_chip)
+    order = kfu.expsum_order(shape, jnp.bfloat16, acc_dtype, K, device)
+    assert order is not None
+    rates, coeffs = fmem.fit_expsum(40, 0.15, K)
+    compiled = jax.jit(
+        lambda g, a, p, s: kfu.expsum_apply(
+            g, a, p, s, rates=rates, coeffs=coeffs, alpha=0.02, beta=0.008,
+            order=order), donate_argnums=(1, 2)).lower(
         _sds(shape, jnp.bfloat16, one_chip),
         _sds((K,) + shape, acc_dtype, one_chip),
-        _sds((K,), jnp.float32, one_chip), _sds((K,), jnp.float32, one_chip),
-        alpha=0.02, beta=0.008).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        _sds(shape, jnp.bfloat16, one_chip),
+        _sds((), jnp.float32, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    moved = [ln for ln in hlo.splitlines()
+             if re.search(r" (copy|transpose)\(", ln) and "[]" not in
+             ln.split("=", 1)[1].split(" ")[1]]
+    assert not moved, moved
 
 
 _STEPS = {}
 
 
 def _compiled_step(one_chip, use_kernel):
-    """The step chip_smoke.py runs (metrics on, state donated), compiled
-    once per test module; returns (compiled, abstract state)."""
+    """The benchmark cell's step at chip_smoke.py's cut (metrics off, state
+    donated), compiled once per test module; returns (compiled, abstract
+    state)."""
     if use_kernel not in _STEPS:
-        trainer = build_trainer(**CHIP_TRAIN, use_kernel=use_kernel,
-                                collect_metrics=True)
+        trainer = build_trainer(**CHIP_TRAIN, use_kernel=use_kernel)
         state = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
                              abstract_train_state(trainer.cfg, trainer.tc,
                                                   trainer.n_agents))
@@ -98,15 +134,17 @@ def _compiled_step(one_chip, use_kernel):
                                            "seq"))
         batch = {k: _sds((A, B, S), jnp.int32, one_chip)
                  for k in ("tokens", "labels")}
-        _STEPS[use_kernel] = (trainer.step_fn.lower(state, batch).compile(),
-                              state)
+        with jax.default_device(_device(one_chip)):
+            lowered = trainer.step_fn.lower(state, batch)
+        _STEPS[use_kernel] = (lowered.compile(), state)
     return _STEPS[use_kernel]
 
 
 @pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "fused"])
 def test_one_chip_train_step_fits(one_chip, use_kernel):
-    """The step chip_smoke.py runs (metrics on, state donated) leaves at
-    least HEADROOM_BYTES of the chip free."""
+    """The cell's step (state donated) leaves at least HEADROOM_BYTES of the
+    chip free.  The exp-sum update is the fused kernel on the TPU whatever
+    ``use_kernel`` says: that flag is the exact mode's."""
     compiled, state = _compiled_step(one_chip, use_kernel)
     mem = compiled.memory_analysis()
     state_bytes = sum(s.size * s.dtype.itemsize
@@ -115,7 +153,100 @@ def test_one_chip_train_step_fits(one_chip, use_kernel):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used <= HBM_BYTES - HEADROOM_BYTES, used / 2 ** 30
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _entry(hlo: str) -> list:
+    """The ENTRY computation's instructions as (name, shape text, rest):
+    rest begins with the opcode."""
+    out = []
+    for ln in hlo[hlo.index("\nENTRY "):].splitlines()[1:]:
+        if ln.startswith("}"):
+            break
+        m = re.match(r"\s+(?:ROOT )?%(\S+) = (.*)$", ln)
+        if not m:
+            continue
+        rhs, depth, end = m.group(2), 0, m.group(2).find(" ")
+        if rhs.startswith("("):               # a tuple shape holds spaces
+            for end, ch in enumerate(rhs, 1):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+        out.append((m.group(1), rhs[:end], rhs[end:].lstrip()))
+    return out
+
+
+def _moves_once(kind: str, rest: str) -> bool:
+    """Operations on the way to the kernel that read each byte once: a
+    bitcast (moves nothing), and XLA's prefetch of an operand into VMEM,
+    async slices joined by a ConcatBitcast."""
+    return kind in ("bitcast", "slice-start", "slice-done") or (
+        kind == "custom-call" and 'custom_call_target="ConcatBitcast"' in rest)
+
+
+def test_one_chip_step_reads_each_accumulator_once(one_chip):
+    """In the compiled one-chip step, every tiled leaf's accumulators are
+    an operand of one operation only, the kernel's custom call (reached
+    through bitcasts, which move no bytes): no copy, transpose or second
+    fusion reads them."""
+    compiled, state = _compiled_step(one_chip, False)
+    K = CHIP_TRAIN["K"]
+    tiled = {"bf16[" + ",".join(map(str, (K,) + p.shape)) + "]"
+             for p in jax.tree.leaves(state.params)
+             if kfu.expsum_order(p.shape, p.dtype, jnp.bfloat16, K,
+                                 _device(one_chip)) is not None}
+    assert len(tiled) >= 5
+    entry = _entry(compiled.as_text())
+    users = {}
+    for name, _, rest in entry:
+        for op in re.findall(r"%([\w.\-]+)", rest.split(", metadata")[0]):
+            users.setdefault(op, []).append(name)
+    kind = {name: re.match(r"([\w\-]+)\(", rest).group(1)
+            for name, _, rest in entry if re.match(r"[\w\-]+\(", rest)}
+    text = {name: rest for name, _, rest in entry}
+    found = 0
+    for name, shape, rest in entry:
+        if not (rest.startswith("parameter(") and shape.split("{")[0]
+                in tiled):
+            continue
+        found += 1
+        frontier, readers = [name], set()
+        while frontier:
+            for u in users.get(frontier.pop(), []):
+                if _moves_once(kind[u], text[u]):
+                    frontier.append(u)
+                else:
+                    readers.add(u)
+        assert len(readers) == 1, (name, shape, readers)
+        reader, = readers
+        assert kind[reader] == "custom-call" and \
+            "tpu_custom_call" in text[reader], (name, reader)
+    assert found >= len(tiled)
+
+
+def test_four_chip_update_adds_no_collective(topology):
+    """The four-agent step over a (4, 1) ``data`` x ``model`` mesh of the
+    2x2 chips: the fused update runs per shard, so inside ``frodo.update``
+    the only collective is the clip norm's scalar all-reduce."""
+    mesh = Mesh(np.array(topology.devices).reshape(4, 1), ("data", "model"))
+    trainer = build_trainer(**FOUR_CHIP_TRAIN, mesh=mesh)
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        abstract_train_state(trainer.cfg, trainer.tc, trainer.n_agents),
+        trainer.state_shardings)
+    A, B, S = (FOUR_CHIP_TRAIN[k] for k in ("agents", "batch_per_agent",
+                                            "seq"))
+    rows = NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
+    batch = {k: jax.ShapeDtypeStruct((A, B, S), jnp.int32, sharding=rows)
+             for k in ("tokens", "labels")}
+    hlo = trainer.step_fn.lower(state, batch).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    update = [ln for ln in hlo.splitlines() if "frodo.update" in ln]
+    gathers = [ln for ln in update if re.search(r"all-gather", ln)]
+    reduces = [ln.split("=", 1)[1].split(" ")[1] for ln in update
+               if re.search(r" all-reduce(-start)?\(", ln)]
+    assert not gathers, gathers
+    assert all(r.startswith("f32[]") for r in reduces), reduces
 
 
 def hlo_scopes(hlo: str) -> dict:

@@ -174,9 +174,35 @@ def test_compile_cache_leaves_the_env_var_to_jax(monkeypatch, tmp_path):
 
 
 def test_kernel_refuses_to_run_off_tpu_unless_asked():
-    from repro.kernels import ops
-    g = jnp.ones((256,), jnp.float32)
-    acc = jnp.zeros((2, 256), jnp.float32)
-    rates = jnp.asarray([0.5, 0.9], jnp.float32)
+    from repro.kernels import frodo_update as kfu
+    g = jnp.ones((2, 16, 128), jnp.float32)
+    acc = jnp.zeros((2, 2, 16, 128), jnp.float32)
     with pytest.raises(ValueError, match="interpret"):
-        ops.frodo_expsum_update(g, acc, rates, rates, 0.1, 0.01)
+        kfu.expsum_apply(g, acc, g, jnp.float32(1), rates=(0.5, 0.9),
+                         coeffs=(0.5, 0.9), alpha=0.1, beta=0.01)
+
+
+def test_fused_train_step_matches_jnp_step():
+    """Two steps with the exp-sum update as the fused kernel (Pallas'
+    interpret mode) against the same steps with the jnp update, to bf16
+    precision; the matrices go through the kernel, the norms through jnp."""
+    from jax.experimental.pallas import tpu as pltpu
+    batches = [_batch(2, 16, seed=i) for i in range(2)]
+    plain = build_trainer(**SMALL)
+    s1 = plain.init(seed=5)
+    assert "pallas_call" not in str(jax.make_jaxpr(plain.step_fn)(
+        s1, batches[0]))
+    with pltpu.force_tpu_interpret_mode():
+        fused = build_trainer(**SMALL)
+        s2 = fused.init(seed=5)
+        jaxpr = str(jax.make_jaxpr(fused.step_fn)(s2, batches[0]))
+        assert jaxpr.count("frodo_expsum_apply") >= 4
+        for batch in batches:
+            s2, m2 = fused.step_fn(s2, batch)
+            s1, m1 = plain.step_fn(s1, batch)
+            np.testing.assert_allclose(np.asarray(m2["agent_loss"]),
+                                       np.asarray(m1["agent_loss"]),
+                                       rtol=1e-2)
+    for a, b in zip(jax.tree.leaves(s2), jax.tree.leaves(s1)):
+        x, y = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(x - y) <= 1e-2 * np.linalg.norm(y) + 1e-6
