@@ -1,13 +1,13 @@
-"""Plain float32 reference of the first steps of FrODO training for the dense
-sliding-window transformer (h2o-danube-1.8b, arXiv:2401.16818).
+"""Plain float32 reference of the first steps of FrODO training, for any
+model that a configuration brings (``bench/models/<model_code>.py``).
 
 It imports nothing of the program.  Straightforward ``jax.numpy`` under
 ``default_matmul_precision("highest")``: no scan, no kernels, no
 checkpoint policies.  So that it fits one chip at the timed sizes, each
-agent's gradient is taken layer by layer (the forward pass keeps each
-layer's input; the backward pass takes each layer's ``vjp`` from it), the
-update and the consensus mix go leaf by leaf, and the past gradients of the
-memory wait in host memory.
+agent's gradient is taken layer by layer by the model's ``agent_grad``
+(the forward pass keeps each layer's input; the backward pass takes each
+layer's ``vjp`` from it), the update and the consensus mix go leaf by
+leaf, and the past gradients of the memory wait in host memory.
 
 One step, for every agent i (Algorithm 1 of FrODO, with the exponential-sum
 memory the configuration states):
@@ -18,17 +18,14 @@ memory the configuration states):
     x   <- W x                          (complete graph: the mean)
 
 The memory is kept as the past gradients themselves, which is exact for the
-few steps that are compared.  The model follows the published description
-with the program's conventions where the paper leaves a choice: RoPE
-rotates interleaved pairs, GQA query head h reads key/value head
-h // (H / G), RMSNorm scales after normalising.
+few steps that are compared.
 
 ``quant="int8"`` is the control: every matmul operand of the forward pass
 rounded to int8 with one scale per tensor (straight-through in the
-backward pass).  ``fault`` plants one of the faults the comparison must
-catch: ``"unchanged"`` (the state is returned as it came), ``"half_batch"``
-(the loss is the mean over half of the tokens), ``"no_mix"`` (no exchange
-between agents).
+backward pass), which a model's matmuls get through ``mm``.  ``fault``
+plants one of the faults the comparison must catch: ``"unchanged"`` (the
+state is returned as it came), ``"half_batch"`` (the loss is the mean over
+half of the tokens), ``"no_mix"`` (no exchange between agents).
 """
 from __future__ import annotations
 
@@ -39,7 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-NEG = -1e30
 
 
 # ------------------------------------------------------------ the memory
@@ -73,7 +69,7 @@ def mixing_matrix(tr: dict) -> np.ndarray:
     return np.full((A, A), 1.0 / A)
 
 
-# ------------------------------------------------------------- the model
+# ------------------------------------------------ quantised matmuls
 
 def _q(x, quant):
     if quant is None:
@@ -85,141 +81,34 @@ def _q(x, quant):
     return x + jax.lax.stop_gradient(xq - x)
 
 
-def _mm(spec, a, b, quant):
+def mm(spec, a, b, quant):
+    """``einsum`` at ``highest`` precision, with the operands rounded as
+    ``quant`` says (None: not at all)."""
     return jnp.einsum(spec, _q(a, quant), _q(b, quant), precision=HIGHEST)
 
 
-def rmsnorm(scale, x, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def rope(x, theta):
-    """x: (B, S, heads, hd); rotates interleaved pairs (0,1), (2,3), ..."""
-    S, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
-    ang = np.arange(S)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                     -1).reshape(x.shape)
-
-
-def block(bp, x, m, quant=None):
-    """One decoder layer: pre-norm GQA attention (causal, sliding window)
-    and a gated SiLU MLP, each added to the residual stream."""
-    H, G = m["n_heads"], m["n_kv_heads"]
-    hd = m.get("head_dim") or m["d_model"] // H
-    S = x.shape[1]
-    h = rmsnorm(bp["ln1/scale"], x, m["norm_eps"])
-    q = rope(_mm("bsd,dhk->bshk", h, bp["attn/wq/w"], quant), m["rope_theta"])
-    k = rope(_mm("bsd,dgk->bsgk", h, bp["attn/wk/w"], quant), m["rope_theta"])
-    v = _mm("bsd,dgk->bsgk", h, bp["attn/wv/w"], quant)
-    k = jnp.repeat(k, H // G, axis=2)
-    v = jnp.repeat(v, H // G, axis=2)
-    s = _mm("bshk,bthk->bhst", q, k, quant) / np.sqrt(hd)
-    dist = np.arange(S)[:, None] - np.arange(S)[None, :]
-    ok = dist >= 0
-    if m["window"] > 0:
-        ok &= dist < m["window"]
-    s = jnp.where(jnp.asarray(ok), s, NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    o = _mm("bhst,bthk->bshk", p, v, quant)
-    x = x + _mm("bshk,hkd->bsd", o, bp["attn/wo/w"], quant)
-    h = rmsnorm(bp["ln2/scale"], x, m["norm_eps"])
-    up = _mm("bsd,df->bsf", h, bp["mlp/up/w"], quant)
-    if m["gated_mlp"]:
-        up = jax.nn.silu(_mm("bsd,df->bsf", h, bp["mlp/gate/w"], quant)) * up
-    else:
-        up = jax.nn.silu(up)
-    return x + _mm("bsf,fd->bsd", up, bp["mlp/down/w"], quant)
-
-
-def head_loss(ln_f, w, x, labels, m, quant=None, half=False):
-    """Token-mean cross entropy of the final norm and the LM head."""
-    h = rmsnorm(ln_f, x, m["norm_eps"])
-    logits = _mm("bsd,dv->bsv", h, w, quant)
-    ce = (jax.nn.logsumexp(logits, -1)
-          - jnp.take_along_axis(logits, labels[..., None], -1)[..., 0])
-    if half:
-        ce = ce[:, : ce.shape[1] // 2]
-    return jnp.mean(ce)
-
-
-@partial(jax.jit, static_argnames=("m", "quant"))
-def _block_fwd(bp, x, m, quant):
-    return block(bp, x, dict(m), quant)
-
-
-@partial(jax.jit, static_argnames=("m", "quant"))
-def _block_bwd(bp, x, dy, m, quant):
-    _, vjp = jax.vjp(lambda p, z: block(p, z, dict(m), quant), bp, x)
-    return vjp(dy)
-
-
-@partial(jax.jit, static_argnames=("m", "quant", "half"))
-def _head_grad(ln_f, w, x, labels, m, quant, half):
-    return jax.value_and_grad(
-        lambda a, b, z: head_loss(a, b, z, labels, dict(m), quant, half),
-        argnums=(0, 1, 2))(ln_f, w, x)
-
-
-@jax.jit
-def _embed(table, tokens):
-    return table[tokens]
-
-
-@jax.jit
-def _embed_grad(table, tokens, dx):
-    return jnp.zeros_like(table).at[tokens.reshape(-1)].add(
-        dx.reshape(-1, dx.shape[-1]))
-
-
-def agent_grad(p: dict, tokens, labels, m: dict, quant=None, half=False):
-    """(loss, grads) of one agent, layer by layer; ``p`` is flat f32 with
-    each layer's leaves apart (``split_layers``)."""
-    mk = tuple(sorted(m.items()))
-    L = m["n_layers"]
-    layer = [{k[len(f"blocks.{i}/"):]: v for k, v in p.items()
-              if k.startswith(f"blocks.{i}/")} for i in range(L)]
-    xs = [_embed(p["embed/table"], tokens)]
-    for bp in layer:
-        xs.append(_block_fwd(bp, xs[-1], mk, quant))
-    w = p["embed/table"].T if m["tie_embeddings"] else p["lm_head/w"]
-    loss, (d_lnf, d_w, dx) = _head_grad(p["ln_f/scale"], w, xs[-1], labels,
-                                        mk, quant, half)
-    grads = {"ln_f/scale": d_lnf}
-    for i in reversed(range(L)):
-        d, dx = _block_bwd(layer[i], xs[i], dx, mk, quant)
-        grads.update({f"blocks.{i}/{k}": v for k, v in d.items()})
-        xs[i + 1] = None
-    d_table = _embed_grad(p["embed/table"], tokens, dx)
-    if m["tie_embeddings"]:
-        d_table = d_table + d_w.T
-    else:
-        grads["lm_head/w"] = d_w
-    grads["embed/table"] = d_table
-    return loss, grads
-
-
-def split_layers(flat: dict, n_layers: int) -> dict:
-    """``blocks/<leaf>`` of shape (L, ...) -> ``blocks.<i>/<leaf>``."""
+def split_layers(flat: dict, stacks: dict) -> dict:
+    """``<stack>/<leaf>`` of shape (L, ...) -> ``<stack>.<i>/<leaf>``, for
+    each top-level ``{stack: L}`` of the model's ``stacks``."""
     out = {}
     for k, v in flat.items():
-        if k.startswith("blocks/"):
-            for i in range(n_layers):
-                out[f"blocks.{i}/{k[len('blocks/'):]}"] = v[i]
+        stack = k.split("/", 1)[0]
+        if stack in stacks:
+            for i in range(stacks[stack]):
+                out[f"{stack}.{i}/{k[len(stack) + 1:]}"] = v[i]
         else:
             out[k] = v
     return out
 
 
-def join_layers(norms: dict) -> dict:
+def join_layers(norms: dict, stacks: dict) -> dict:
     """Per-layer norms -> the norm of each stacked leaf."""
     out = {}
     for k, v in norms.items():
-        if k.startswith("blocks."):
-            k = "blocks/" + k.split("/", 1)[1]
+        head, rest = k.split("/", 1)
+        stack, _, i = head.rpartition(".")
+        if stack in stacks and i.isdigit():
+            k = f"{stack}/{rest}"
         out[k] = out.get(k, 0.0) + v * v
     return {k: float(np.sqrt(v)) for k, v in out.items()}
 
@@ -263,14 +152,16 @@ def _scale(g, s):
     return {k: v * s for k, v in g.items()}
 
 
-def run(params: list, batches: list, config: dict, devices: list,
+def run(model, params: list, batches: list, config: dict, devices: list,
         quant=None, fault=None) -> dict:
-    """Runs ``len(batches)`` steps from ``params`` (``agent_slices``: one
-    flat f32 dict per agent, agent a on ``devices[a % len(devices)]``) and
-    returns, per agent, the step losses, the per-leaf norms of the first
-    (clipped) gradient and the per-leaf norms of the parameters' change,
-    leaves as the program stacks them."""
+    """Runs ``len(batches)`` steps of ``model`` (the configuration's model
+    module) from ``params`` (``agent_slices``: one flat f32 dict per agent,
+    agent a on ``devices[a % len(devices)]``) and returns, per agent, the
+    step losses, the per-leaf norms of the first (clipped) gradient and the
+    per-leaf norms of the parameters' change, leaves as the program stacks
+    them."""
     m, tr = config["model"], config["trainer"]
+    stacks = model.stacks(m)
     A = len(params)
     dev = [devices[a % len(devices)] for a in range(A)]
     W = mixing_matrix(tr)
@@ -284,10 +175,12 @@ def run(params: list, batches: list, config: dict, devices: list,
     losses, first = [], None
     with jax.default_matmul_precision("highest"):
         for t, batch in enumerate(batches):
-            out = [agent_grad(x[a],
-                              jax.device_put(batch["tokens"][a], dev[a]),
-                              jax.device_put(batch["labels"][a], dev[a]),
-                              m, quant, half) for a in range(A)]
+            out = [model.agent_grad(x[a],
+                                    jax.device_put(batch["tokens"][a],
+                                                   dev[a]),
+                                    jax.device_put(batch["labels"][a],
+                                                   dev[a]),
+                                    m, quant, half) for a in range(A)]
             losses.append([float(l) for l, _ in out])
             grads = [g for _, g in out]
             del out
@@ -295,7 +188,7 @@ def run(params: list, batches: list, config: dict, devices: list,
             scale = min(1.0, clip / max(gn, 1e-9))
             if t == 0:
                 first = [join_layers({k: float(v) * scale
-                                      for k, v in _norms(g).items()})
+                                      for k, v in _norms(g).items()}, stacks)
                          for g in grads]
             if fault == "unchanged":
                 continue
@@ -312,7 +205,7 @@ def run(params: list, batches: list, config: dict, devices: list,
         del past
         change = [join_layers(
             {k: float(_diff_norm(v, jax.device_put(x0[a][k], dev[a])))
-             for k, v in x[a].items()}) for a in range(A)]
+             for k, v in x[a].items()}, stacks) for a in range(A)]
     return {"losses": losses, "first_grad": first, "change": change}
 
 
@@ -334,7 +227,7 @@ def mix(x: list, W: np.ndarray, dev: list) -> list:
     return out
 
 
-def agent_slices(stacked_flat: dict, n_agents: int, n_layers: int,
+def agent_slices(stacked_flat: dict, n_agents: int, stacks: dict,
                  devices: list) -> list:
     """Per-agent f32 copies of the benchmark's stacked weights, each layer's
     leaves apart."""
@@ -343,6 +236,6 @@ def agent_slices(stacked_flat: dict, n_agents: int, n_layers: int,
         d = devices[a % len(devices)]
         one = {k: v[a] for k, v in stacked_flat.items()}
         out.append({k: jax.device_put(v, d).astype(jnp.float32)
-                    for k, v in split_layers(one, n_layers).items()})
+                    for k, v in split_layers(one, stacks).items()})
     return out
 

@@ -25,7 +25,7 @@ from pathlib import Path
 
 import numpy as np
 
-from harness import check, flops, peaks, reference, spec, tokens, tree
+from harness import check, peaks, reference, spec, tokens, tree
 from harness import trace_reduce, weights
 
 
@@ -110,26 +110,6 @@ def devices_for(chips: int, require_accelerator: bool):
     return devs
 
 
-def check_model(cfg, m: dict) -> None:
-    """The program's model must be the configuration file's."""
-    have = {"family": cfg.family, "n_layers": cfg.n_layers,
-            "d_model": cfg.d_model, "n_heads": cfg.n_heads,
-            "n_kv_heads": cfg.n_kv_heads, "head_dim": cfg.hd(),
-            "d_ff": cfg.d_ff, "vocab": cfg.vocab, "window": cfg.window,
-            "rope_theta": cfg.rope_theta, "norm_eps": cfg.norm_eps,
-            "activation": cfg.activation, "gated_mlp": cfg.gated_mlp,
-            "tie_embeddings": cfg.tie_embeddings,
-            "param_dtype": cfg.param_dtype,
-            "compute_dtype": cfg.compute_dtype}
-    diff = {k: (v, m[k]) for k, v in have.items() if v != m[k]}
-    if cfg.attn_type != "swa" or cfg.rope_fraction != 1.0 or cfg.qk_norm \
-            or cfg.logit_softcap:
-        diff["attention"] = (cfg.attn_type, "swa with full RoPE")
-    if diff:
-        raise ValueError(f"the program's model differs from the "
-                         f"configuration file: {diff}")
-
-
 def quiet():
     """The trainer logs to standard output; the result line must be last
     there, so its lines go to standard error."""
@@ -170,11 +150,13 @@ class Session:
                                     f"{cfg['mesh']}")
         self.trainer = build_trainer(**tr, seq=S, batch_per_agent=B,
                                      mesh=mesh)
-        check_model(self.trainer.cfg, m)
+        cell.model.check_model(self.trainer.cfg, m)
         opt = build_optimizer(self.trainer.tc)
         self.shard = shard = self.trainer.state_shardings
+        shapes = cell.model.leaf_shapes(m)
         self.init_params = jax.jit(
-            lambda key: weights.stacked_params(key, m, A),
+            lambda key: weights.stacked_params(key, shapes,
+                                               m["param_dtype"], A),
             **({"out_shardings": shard.params} if shard else {}))
         self.init_opt = jax.jit(
             opt.init, **({"out_shardings": shard.opt_state} if shard else {}))
@@ -250,17 +232,18 @@ class Session:
     def reference(self, seed: int, quant=None, fault=None) -> dict:
         """The reference's first steps from the same weights and tokens;
         run it with the program's state freed."""
-        traffic, cfg = self.cell.traffic, self.cell.config
+        traffic, cfg, model = (self.cell.traffic, self.cell.config,
+                               self.cell.model)
         devs = (list(self.mesh.devices.flat) if self.mesh is not None
                 else self.devs[:1])
         stacked = tree.flatten(self.init_params(weights.seed_key(seed)))
         params = reference.agent_slices(stacked, self.A,
-                                        cfg["model"]["n_layers"], devs)
+                                        model.stacks(cfg["model"]), devs)
         del stacked
         stream = tokens.make_stream(traffic, cfg["model"]["vocab"], self.A,
                                     seed)
         batches = [next(stream) for _ in range(traffic["check_steps"])]
-        return reference.run(params, batches, cfg, devs, quant=quant,
+        return reference.run(model, params, batches, cfg, devs, quant=quant,
                              fault=fault)
 
     def peak_bytes(self) -> int:
@@ -334,7 +317,7 @@ def run(root: Path, cell_name: str, seed: int, seconds: float, trace: bool,
                "traffic": cell.traffic,
                "peaks": peaks.PEAKS.get(devs[0].device_kind),
                "chips": cell.chips,
-               "flops_per_token": flops.train_flops_per_token(
+               "flops_per_token": cell.model.train_flops_per_token(
                    cell.config["model"], cell.traffic["seq_len"])}
     metrics = cell.read_metrics("per_layer" if trace else "end_to_end",
                                 run_rec)

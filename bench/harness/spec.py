@@ -4,6 +4,14 @@ Everything that belongs to one configuration, one traffic mix or one metric
 is a file of its own, found by name:
 
 * configuration: the ``file`` its ``configs`` entry names;
+* the model the configuration brings: ``bench/models/<model_code>.py``,
+  with ``model_code`` a key of the configuration file.  The module gives
+  ``leaf_shapes(m)`` (``{path: (shape, fan_in)}`` of one agent),
+  ``stacks(m)`` (``{prefix: layers}`` of each stacked layer group),
+  ``train_flops_per_token(m, seq_len)``, ``check_model(cfg, m)`` (raises
+  where the program's model differs from the file's ``model``) and
+  ``agent_grad(p, tokens, labels, m, quant, half)`` (one agent's float32
+  loss and gradients, layer by layer, for ``harness/reference.py``);
 * traffic mix: ``bench/traffic/<traffic>.json``;
 * the limits of a cell's comparison: ``bench/limits/<cell>.json``;
 * a metric's reader: ``bench/metrics/<metric>.py``, whose ``read(run)``
@@ -33,6 +41,9 @@ class Cell:
         configs = {c["name"]: c for c in self.bench["configs"]}
         entry = configs[self.workload["config"]]
         self.config = json.loads((self.root / entry["file"]).read_text())
+        code = self.config["model_code"]
+        self.model = load(self.bench_dir / "models" / f"{code}.py",
+                          f"bench_model_{code}")
         self.traffic = self._json("traffic", self.workload["traffic"])
         self.limits = self._json("limits", name)
         self.chips = int(self.workload["chips"])
@@ -46,13 +57,8 @@ class Cell:
                 if "workloads" not in m or self.name in m["workloads"]]
 
     def reader(self, metric: str):
-        path = self.bench_dir / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}",
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load(self.bench_dir / "metrics" / f"{metric}.py",
+                    f"bench_metric_{metric}").read
 
     def read_metrics(self, kind: str, run: dict) -> dict:
         out = {}
@@ -61,3 +67,12 @@ class Cell:
             if value is not None:
                 out[m["name"]] = {"value": value, "unit": m["unit"]}
         return out
+
+
+def load(path: Path, name: str):
+    """The module in the file ``path``, under ``name``."""
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -15,7 +15,10 @@ on the trace's one clock.  ``reduce`` computes, over the measured window:
   executions;
 * the breakdown: the operations that took the most device time, each with
   the program's source line where the trace names one, and the longest
-  idle gaps, each named by the host annotation it fell in.
+  idle gaps, each named by the host annotation it fell in;
+* per chip, the device time by the program's name scopes and the idle
+  time by its host spans (``scope_ns``, ``idle_by_span_ns``: see
+  ``harness/scopes.py``, whose ``add`` ``load`` calls).
 
 Loop and call operations (``while``, ``conditional``, ``call``) span the
 operations of their bodies: they count towards busy time and nothing else.
@@ -27,6 +30,8 @@ import gzip
 import os
 import re
 from typing import Iterable, List, Tuple
+
+from harness import scopes
 
 HOST_NAMES = ("bench.window", "bench.data", "train")
 COLLECTIVES = ("all-reduce", "all-gather", "collective-permute",
@@ -83,7 +88,8 @@ def load(path: str) -> dict:
     for js in glob.glob(os.path.join(os.path.dirname(path),
                                      "*.trace.json.gz")):
         sources.update(load_sources(js))
-    return {"chips": chips, "host": host, "sources": sources}
+    return scopes.add({"chips": chips, "host": host, "sources": sources},
+                      path)
 
 
 NAME = re.compile(r'"name":"([^"]+)"')
@@ -204,6 +210,9 @@ def reduce(record: dict, n_top: int = 10) -> dict:
                 op_time[n] = op_time.get(n, 0.0) + min(e, hi) - max(s, lo)
         for s, e in gaps(busy, lo, hi):
             idle.append((e - s, (s, e)))
+    for c, split in zip(per_chip, scopes.reduce(record)["chips"]):
+        c["scope_ns"] = split["scope_ns"]
+        c["idle_by_span_ns"] = split["idle_by_span_ns"]
     nchips = max(len(per_chip), 1)
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:n_top]
     idle.sort(key=lambda x: -x[0])

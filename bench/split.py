@@ -11,8 +11,9 @@ idle while the host is in ``train.metrics``), every scope and span by name,
 and the longest idle gaps labelled by the innermost host span.  A program
 without those scopes and spans gives None for their numbers.
 
-``trace_reduce.load`` is wrapped for the run, so the trace is read where
-``bench/run.py`` reads it, before the run deletes it.
+``trace_reduce.load`` (which adds the scopes and spans to its record) is
+wrapped for the run, so the trace is read where ``bench/run.py`` reads it,
+before the run deletes it.
 """
 import time
 
@@ -39,7 +40,7 @@ def main(argv=None) -> int:
     load = trace_reduce.load
 
     def load_and_split(path):
-        record = scopes.add(load(path), path)
+        record = load(path)
         reduced.update(scopes.reduce(record))
         return record
 

@@ -105,15 +105,19 @@ def test_control_is_not_correct():
     limits = json.loads((tiny.BENCH / "limits" /
                          "danube-1chip.seq2048.json").read_text())
     m, A, devs = cfg["model"], cfg["trainer"]["agents"], jax.devices()[:1]
-    init = jax.jit(lambda k: weights.stacked_params(k, m, A))
+    model = tiny.model()
+    shapes = model.leaf_shapes(m)
+    init = jax.jit(lambda k: weights.stacked_params(k, shapes,
+                                                    m["param_dtype"], A))
     for seed in (3, 4, 5):
         out = {}
         for quant in (None, "int8"):
             stacked = tree.flatten(init(weights.seed_key(seed)))
-            params = reference.agent_slices(stacked, A, 1, devs)
+            params = reference.agent_slices(stacked, A, model.stacks(m),
+                                            devs)
             stream = tokens.make_stream(traffic, m["vocab"], A, seed)
             out[quant] = reference.run(
-                params, [next(stream) for _ in range(3)], cfg, devs,
+                model, params, [next(stream) for _ in range(3)], cfg, devs,
                 quant=quant)
         ok, lines = check.judge(
             check.compare(calibrate.as_program(out["int8"]), out[None]),

@@ -1,12 +1,13 @@
-"""The benchmark's copies of the program's FLOP arithmetic and token
-generator."""
+"""The benchmark's copies of the program's FLOP arithmetic (the shared
+part in ``harness/flops.py``, the dense model's in ``models/dense_swa.py``)
+and token generator."""
 import json
 
 import numpy as np
 import pytest
 
 from harness import flops, tokens
-from tiny import BENCH
+from tiny import BENCH, model
 
 
 def config(name):
@@ -23,7 +24,7 @@ def test_flops_per_token_match_the_hand_count(name, layers, gflop):
     # 62.9 M at 2 layers, 6 x 359.79 M + 125.8 M at 4, 6 x 498.76 M +
     # 188.8 M at 6
     m = dict(config(name)["model"], n_layers=layers)
-    assert flops.train_flops_per_token(m, 2048) / 1e9 == pytest.approx(
+    assert model().train_flops_per_token(m, 2048) / 1e9 == pytest.approx(
         gflop, abs=5e-4)
 
 
@@ -34,7 +35,7 @@ def test_matmul_params_match_the_programs_count():
     m = config("h2o-danube-1.8b.1chip")["model"]
     cfg = REG.reduced_layers(REG.get_config("h2o-danube-1.8b"),
                              m["n_layers"])
-    assert flops.matmul_params(m) == param_counts(cfg)["active"]
+    assert model().matmul_params(m) == param_counts(cfg)["active"]
 
 
 def test_window_bounds_the_keys_per_token():

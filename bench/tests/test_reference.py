@@ -31,7 +31,8 @@ def program_f32_readings(m, tr, traffic, seed, steps=3):
     step = jax.jit(make_train_step(cfg, tc, A))
     opt = build_optimizer(tc)
     key = weights.seed_key(seed)
-    p0 = weights.stacked_params(key, m, A)
+    p0 = weights.stacked_params(key, tiny.model().leaf_shapes(m),
+                                m["param_dtype"], A)
     state = TrainState(p0, opt.init(p0), jnp.zeros((), jnp.int32))
     stream = tokens.make_stream(traffic, m["vocab"], A, seed)
     rates, _ = reference.expsum_fit(tr["T"], tr["lam"], tr["K"])
@@ -63,12 +64,15 @@ def test_reference_matches_the_float32_train_step(agents):
     traffic["seq_len"] = 128            # past the window of 64
     seed = 2**31 + 11
     prog = program_f32_readings(m, cfg["trainer"], traffic, seed)
+    model = tiny.model()
     stacked = tree.flatten(weights.stacked_params(
-        weights.seed_key(seed), m, agents))
+        weights.seed_key(seed), model.leaf_shapes(m), m["param_dtype"],
+        agents))
     devs = jax.devices()[:1]
-    params = reference.agent_slices(stacked, agents, m["n_layers"], devs)
+    params = reference.agent_slices(stacked, agents, model.stacks(m), devs)
     stream = tokens.make_stream(traffic, m["vocab"], agents, seed)
-    ref = reference.run(params, [next(stream) for _ in range(3)], cfg, devs)
+    ref = reference.run(model, params, [next(stream) for _ in range(3)], cfg,
+                        devs)
     gaps = check.compare(prog, ref)
     for name, (value, where) in gaps.items():
         assert value < 2e-5, (name, value, where)

@@ -112,6 +112,32 @@ def test_per_step_ms_synthetic():
     assert got["steps"] == 2
 
 
+def test_trace_reduce_carries_the_split_and_the_readers_read_it():
+    """``trace_reduce.reduce`` adds each chip's ``scope_ns`` and
+    ``idle_by_span_ns`` beside its own keys, and the per-layer readers take
+    the per-step numbers from them; a trace without the step's scopes and
+    spans gives them nothing to read."""
+    from harness import spec
+    import tiny
+
+    rec = synthetic()
+    base, split = tr.reduce(rec), sc.reduce(rec)
+    for c, s in zip(base["chips"], split["chips"]):
+        assert c["scope_ns"] == s["scope_ns"]
+        assert c["idle_by_span_ns"] == s["idle_by_span_ns"]
+    cell = spec.Cell(tiny.ROOT, "danube-1chip.seq2048")
+    names = ("fwd_bwd_ms", "update_ms", "mix_ms", "unscoped_ms",
+             "fetch_idle_ms")
+    want = sc.per_step_ms(split)
+    for name in names:
+        assert cell.reader(name)({"trace": base}) == pytest.approx(
+            want[name], rel=1e-12)
+        assert cell.reader(name)({"trace": None}) is None
+    bare = dict(rec, scopes={}, spans=[])
+    got = {n: cell.reader(n)({"trace": tr.reduce(bare)}) for n in names}
+    assert got == dict.fromkeys(names)
+
+
 def test_without_program_scopes_and_spans():
     """A record of a program without the step's scopes and the trainer's
     spans: their numbers are None, and the gaps keep trace_reduce's

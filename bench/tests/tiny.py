@@ -1,6 +1,7 @@
 """A tiny copy of the benchmark for tests on the CPU: the smoke-sized
 h2o-danube-1.8b (2 layers, d_model 256, 8/2 heads, window 64) with two
 agents, added as new files to a copy of ``BENCHMARK.json`` and ``bench/``."""
+import functools
 import json
 import os
 import shutil
@@ -16,6 +17,14 @@ MODEL = {"arch": "h2o-danube-1.8b", "family": "dense", "n_layers": 2,
          "norm_eps": 1e-06, "activation": "silu", "gated_mlp": True,
          "tie_embeddings": False, "param_dtype": "bfloat16",
          "compute_dtype": "bfloat16"}
+
+
+@functools.cache
+def model(code: str = "dense_swa"):
+    """The model module ``bench/models/<code>.py``, as the harness loads
+    it."""
+    from harness import spec
+    return spec.load(BENCH / "models" / f"{code}.py", f"bench_model_{code}")
 
 
 def make_copy(dest: Path, limits=None, chips: int = 1) -> Path:
