@@ -9,6 +9,11 @@ The same trainer and state then run in chunks of ``Trainer.run`` until
 ``seconds`` have passed.  Afterwards, with the program's state freed, the
 float32 reference re-runs the first steps from the same weights and
 tokens, and ``check`` compares the two.
+
+Besides the clock and the trace, a metric's reader gets what the program
+counts: the trainer's step records of the window (``history``: every
+``log_every``-th step's scalars, as ``Trainer.history`` keeps them) and the
+program's ``obs.record`` records of set-up and the window (``records``).
 """
 from __future__ import annotations
 
@@ -25,7 +30,7 @@ from pathlib import Path
 
 import numpy as np
 
-from harness import check, peaks, reference, spec, tokens, tree
+from harness import check, peaks, reference, scopes, spec, tokens, tree
 from harness import trace_reduce, weights
 
 
@@ -110,6 +115,29 @@ def devices_for(chips: int, require_accelerator: bool):
     return devs
 
 
+def fits(want, have) -> bool:
+    """Whether the trees ``want`` (the program's parameters) and ``have``
+    (the benchmark's) match in structure and in each leaf's shape and
+    dtype."""
+    import jax
+    return jax.tree.structure(want) == jax.tree.structure(have) and all(
+        (a.shape, a.dtype) == (b.shape, b.dtype)
+        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(have)))
+
+
+@contextlib.contextmanager
+def program_records():
+    """The program's ``obs.record`` records while inside, as a list; the
+    sink that was set before is set again after."""
+    from repro import obs
+    sink = obs.MemorySink()
+    before = obs.set_sink(sink)
+    try:
+        yield sink.records
+    finally:
+        obs.set_sink(before)
+
+
 def quiet():
     """The trainer logs to standard output; the result line must be last
     there, so its lines go to standard error."""
@@ -163,10 +191,7 @@ class Session:
         want = abstract_train_state(self.trainer.cfg, self.trainer.tc,
                                     A).params
         have = jax.eval_shape(self.init_params, weights.seed_key(0))
-        if jax.tree.structure(want) != jax.tree.structure(have) or any(
-                (a.shape, a.dtype) != (b.shape, b.dtype)
-                for a, b in zip(jax.tree.leaves(want),
-                                jax.tree.leaves(have))):
+        if not fits(want, have):
             raise ValueError("the benchmark's weights do not fit the "
                              "program's parameter tree")
 
@@ -259,31 +284,32 @@ def run(root: Path, cell_name: str, seed: int, seconds: float, trace: bool,
 
     ses = Session(root, cell_name, require_accelerator)
     cell, trainer = ses.cell, ses.trainer
-    state, feed, prog, excluded = ses.start(seed)
-    setup_s = time.perf_counter() - t_start - excluded
+    with program_records() as records:
+        state, feed, prog, excluded = ses.start(seed)
+        setup_s = time.perf_counter() - t_start - excluded
 
-    # the measured window
-    chunk = int(cell.traffic["chunk_steps"])
-    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
-    if trace:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    feed.marks.clear()
-    logged = len(trainer.history)
-    bounds = []
-    collections = GcPauses()
-    ses.counter.on = collections.on = True
-    w0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("bench.window"):
-        while True:
-            with quiet():
-                state = trainer.run(state, feed, chunk)
-            bounds.append((len(feed.marks), time.perf_counter()))
-            if bounds[-1][1] - w0 >= seconds:
-                break
-    w1 = bounds[-1][1]
-    ses.counter.on = collections.on = False
+        # the measured window
+        chunk = int(cell.traffic["chunk_steps"])
+        trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+        if trace:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        feed.marks.clear()
+        logged = len(trainer.history)
+        bounds = []
+        collections = GcPauses()
+        ses.counter.on = collections.on = True
+        w0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.window"):
+            while True:
+                with quiet():
+                    state = trainer.run(state, feed, chunk)
+                bounds.append((len(feed.marks), time.perf_counter()))
+                if bounds[-1][1] - w0 >= seconds:
+                    break
+        w1 = bounds[-1][1]
+        ses.counter.on = collections.on = False
     if trace:
         jax.profiler.stop_trace()
     step_s, first = [], 0
@@ -291,7 +317,8 @@ def run(root: Path, cell_name: str, seed: int, seconds: float, trace: bool,
         marks = feed.marks[first:n] + [end]
         step_s += [b - a for a, b in zip(marks, marks[1:])]
         first = n
-    window_losses = [h["loss"] for h in trainer.history[logged:]]
+    history = trainer.history[logged:]
+    window_losses = [h["loss"] for h in history]
     peak = ses.peak_bytes()
     del state
     gc.collect()
@@ -314,7 +341,8 @@ def run(root: Path, cell_name: str, seed: int, seconds: float, trace: bool,
     run_rec = {"window_s": w1 - w0, "steps": len(step_s), "step_s": step_s,
                "tokens_per_step": ses.tokens_per_step, "setup_s": setup_s,
                "trace": reduced, "config": cell.config,
-               "traffic": cell.traffic,
+               "traffic": cell.traffic, "model": cell.model,
+               "history": history, "records": records,
                "peaks": peaks.PEAKS.get(devs[0].device_kind),
                "chips": cell.chips,
                "flops_per_token": cell.model.train_flops_per_token(
@@ -340,4 +368,11 @@ def run(root: Path, cell_name: str, seed: int, seconds: float, trace: bool,
             "slowest_steps_ms": sorted(x * 1e3 for x in step_s)[-5:],
             "median_step_ms": float(np.median(step_s)) * 1e3,
             "ref_losses": ref["losses"], "prog_losses": prog["losses"]}
+    if reduced is not None:
+        # the fused exp-sum kernel's share of the update's device time
+        update = scopes.scope_ms(reduced, "frodo.update")
+        kernel = scopes.scope_ms(reduced, "pallas.frodo_expsum_apply")
+        info["expsum_kernel_ms"] = kernel
+        info["expsum_kernel_share"] = (kernel / update if kernel and update
+                                       else None)
     return result, checks, info

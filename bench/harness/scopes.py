@@ -21,6 +21,10 @@ idle time by the program's host spans, from the same profiler trace that
   names no scope are ``unscoped``; operations the trace's first
   ``trace_reduce.load_sources`` bytes do not name are ``unseen``.  Loop and
   call operations are left out, as in ``trace_reduce``'s ``device_ops``.
+* ``scope_path_ns``: the same time by the chain of every name scope in the
+  path, outer to inner, joined by ``/`` (``scope_chain``:
+  ``frodo.update/pallas.frodo_expsum_apply``), with the same ``unscoped``
+  and ``unseen``; ``scope_ms`` reads it for any scope at any depth.
 * ``idle_by_span_ns``: device-idle time by the innermost host span (the
   benchmark's annotations and the program's spans) covering each instant,
   ``other host`` where none does.
@@ -52,6 +56,20 @@ TF_OP = re.compile(r'"tf_op":"([^"]*)"')
 def scope_of(path: str) -> str:
     m = SCOPE.search(path)
     return m.group(0) if m else UNSCOPED
+
+
+def scope_chain(path: str) -> str:
+    """Every name scope in the op_name path, outer to inner, joined by
+    ``/``; a scope that a transformation or remat repeats further in
+    (``train.fwd_bwd/vmap(transpose(jvp(train.fwd_bwd)))``) counts once.
+    A fusion of operations from several places carries their paths joined
+    by ``;``: its chain is that of the first path that names a scope, the
+    one ``scope_of`` takes the outermost scope from."""
+    for one in path.split(";"):
+        chain = dict.fromkeys(SCOPE.findall(one))
+        if chain:
+            return "/".join(chain)
+    return UNSCOPED
 
 
 def load_scopes(path: str, limit: int = 32 << 20) -> dict:
@@ -143,15 +161,19 @@ def reduce(record: dict, n_top: int = 10) -> dict:
     for plane in sorted(record["chips"]):
         ops = [(n, s, e) for n, s, e in record["chips"][plane]["ops"]
                if e > lo and s < hi]
-        scope_ns = {}
+        scope_ns, scope_path_ns = {}, {}
         for n, s, e in ops:
             if tr.op_kind(n) in tr.CONTAINERS:
                 continue
             key = scope_of(scopes[n]) if n in scopes else UNSEEN
             scope_ns[key] = scope_ns.get(key, 0.0) + min(e, hi) - max(s, lo)
+            key = scope_chain(scopes[n]) if n in scopes else UNSEEN
+            scope_path_ns[key] = (scope_path_ns.get(key, 0.0) + min(e, hi)
+                                  - max(s, lo))
         gaps = tr.gaps(tr.union(tr.clip([(s, e) for _, s, e in ops],
                                         lo, hi)), lo, hi)
         chips.append({"plane": plane, "scope_ns": scope_ns,
+                      "scope_path_ns": scope_path_ns,
                       "idle_by_span_ns": by_segment(gaps, segments)})
         idle += [(e - s, (s, e)) for s, e in gaps]
     idle.sort(key=lambda x: -x[0])
@@ -165,9 +187,9 @@ def per_step_ms(reduced: dict) -> dict:
     ``train.fwd_bwd``, ``update_ms`` ``frodo.update``, ``mix_ms`` every
     ``consensus.*``, ``unscoped_ms``), the device-idle time while the host
     fetches the step's metrics (``fetch_idle_ms``, ``train.metrics``), and
-    every scope and span by name.  A number whose scope or span the trace
-    does not hold is None; ``unscoped_ms`` is None unless the trace holds
-    ``train.fwd_bwd``."""
+    every scope, chain of scopes and span by name.  A number whose scope or
+    span the trace does not hold is None; ``unscoped_ms`` is None unless the
+    trace holds ``train.fwd_bwd``."""
     chips, steps = reduced["chips"], reduced["steps"]
     if not chips or not steps:
         return {}
@@ -188,5 +210,16 @@ def per_step_ms(reduced: dict) -> dict:
         **{f"{key[:-3]}_ms": {n: ms(key, lambda k, n=n: k == n)
                               for n in sorted({k for c in chips
                                                for k in c[key]})}
-           for key in ("scope_ns", "idle_by_span_ns")},
+           for key in ("scope_ns", "scope_path_ns", "idle_by_span_ns")},
         "idle_gaps": reduced["idle_gaps"]}
+
+
+def scope_ms(reduced: dict, name: str):
+    """Device time per step, averaged over the chips, of the operations
+    whose chain of scopes holds ``name`` at any depth (one scope, or a run
+    of them joined by ``/``); None where none does.  ``reduced`` is
+    ``reduce``'s or ``trace_reduce.reduce``'s result."""
+    chips, steps = reduced["chips"], reduced["steps"]
+    got = [v for c in chips for k, v in c["scope_path_ns"].items()
+           if f"/{name}/" in f"/{k}/"]
+    return sum(got) / len(chips) / steps * 1e-6 if got and steps else None

@@ -6,7 +6,8 @@ is a file of its own, found by name:
 * configuration: the ``file`` its ``configs`` entry names;
 * the model the configuration brings: ``bench/models/<model_code>.py``,
   with ``model_code`` a key of the configuration file.  The module gives
-  ``leaf_shapes(m)`` (``{path: (shape, fan_in)}`` of one agent),
+  ``leaf_shapes(m)`` (``{path: (shape, fan_in)}`` of one agent, or
+  ``(shape, fan_in, dtype)`` for a leaf kept in a dtype of its own),
   ``stacks(m)`` (``{prefix: layers}`` of each stacked layer group),
   ``train_flops_per_token(m, seq_len)``, ``check_model(cfg, m)`` (raises
   where the program's model differs from the file's ``model``) and

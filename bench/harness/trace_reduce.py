@@ -16,9 +16,10 @@ on the trace's one clock.  ``reduce`` computes, over the measured window:
 * the breakdown: the operations that took the most device time, each with
   the program's source line where the trace names one, and the longest
   idle gaps, each named by the host annotation it fell in;
-* per chip, the device time by the program's name scopes and the idle
-  time by its host spans (``scope_ns``, ``idle_by_span_ns``: see
-  ``harness/scopes.py``, whose ``add`` ``load`` calls).
+* per chip, the device time by the program's name scopes, outermost and
+  chained, and the idle time by its host spans (``scope_ns``,
+  ``scope_path_ns``, ``idle_by_span_ns``: see ``harness/scopes.py``, whose
+  ``add`` ``load`` calls).
 
 Loop and call operations (``while``, ``conditional``, ``call``) span the
 operations of their bodies: they count towards busy time and nothing else.
@@ -212,6 +213,7 @@ def reduce(record: dict, n_top: int = 10) -> dict:
             idle.append((e - s, (s, e)))
     for c, split in zip(per_chip, scopes.reduce(record)["chips"]):
         c["scope_ns"] = split["scope_ns"]
+        c["scope_path_ns"] = split["scope_path_ns"]
         c["idle_by_span_ns"] = split["idle_by_span_ns"]
     nchips = max(len(per_chip), 1)
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:n_top]

@@ -15,14 +15,23 @@ import numpy as np
 from harness import tree
 
 
+def leaf(entry: tuple, dtype: str) -> tuple:
+    """``(shape, fan_in, dtype)`` of one ``leaf_shapes`` entry: a leaf
+    ``(shape, fan_in)`` is in the configuration's ``dtype``, a leaf
+    ``(shape, fan_in, dtype)`` in its own (a router kept in float32)."""
+    shape, fan_in, *own = entry
+    return shape, fan_in, jnp.dtype(own[0] if own else dtype)
+
+
 def agent_params(key, shapes: dict, dtype: str) -> dict:
-    """One agent's parameters (flat) in ``dtype``; ``shapes`` is the
-    model's ``leaf_shapes``: ``{path: (shape, fan_in)}``, where ``fan_in``
-    0 marks a norm scale (1 + 0.1 z) and -1 an embedding table (0.02 z),
-    and any other leaf is z / sqrt(fan_in)."""
-    dt = jnp.dtype(dtype)
+    """One agent's parameters (flat), each in ``dtype`` or its own;
+    ``shapes`` is the model's ``leaf_shapes``: ``{path: (shape, fan_in)}``
+    or ``{path: (shape, fan_in, dtype)}`` (``leaf``), where ``fan_in`` 0
+    marks a norm scale (1 + 0.1 z) and -1 an embedding table (0.02 z), and
+    any other leaf is z / sqrt(fan_in)."""
     out = {}
-    for i, (path, (shape, fan_in)) in enumerate(sorted(shapes.items())):
+    for i, (path, entry) in enumerate(sorted(shapes.items())):
+        shape, fan_in, dt = leaf(entry, dtype)
         z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
         if fan_in == 0:
             v = 1.0 + 0.1 * z

@@ -7,8 +7,10 @@ Runs the cell as ``bench/run.py --trace 1`` does and prints that command's
 result line, then one JSON object from ``harness/scopes.py``: per step,
 ``fwd_bwd_ms`` (``train.fwd_bwd``), ``update_ms`` (``frodo.update``),
 ``mix_ms`` (``consensus.*``), ``unscoped_ms``, ``fetch_idle_ms`` (device
-idle while the host is in ``train.metrics``), every scope and span by name,
-and the longest idle gaps labelled by the innermost host span.  A program
+idle while the host is in ``train.metrics``), every scope, chain of scopes
+(``scope_path_ms``) and span by name, and the longest idle gaps labelled by
+the innermost host span; with them ``expsum_kernel_share``, the share of
+``update_ms`` under the scope ``pallas.frodo_expsum_apply``.  A program
 without those scopes and spans gives None for their numbers.
 
 ``trace_reduce.load`` (which adds the scopes and spans to its record) is
@@ -49,7 +51,9 @@ def main(argv=None) -> int:
                                  args.seconds, True, T_START)
     print(f"bench: {json.dumps(info)}", file=sys.stderr, flush=True)
     print(json.dumps(result))
-    print(json.dumps(scopes.per_step_ms(reduced)), flush=True)
+    split = scopes.per_step_ms(reduced)
+    split["expsum_kernel_share"] = info["expsum_kernel_share"]
+    print(json.dumps(split), flush=True)
     return 0
 
 

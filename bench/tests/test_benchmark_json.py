@@ -51,10 +51,12 @@ def test_entries():
                                           "source"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
+    cells = {w["name"] for w in B["workloads"]}
     for m in B["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better",
                                           "source", "layer", "moves"}
         assert m["moves"] in e2e and LINE.match(m["layer"])
+        assert set(m.get("workloads", cells)) <= cells
     for m in B["end_to_end"] + B["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")

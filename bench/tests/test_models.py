@@ -7,7 +7,14 @@
   for bit.
 * A toy module with two layer stacks (``toy_two_stack.py``), added as new
   files to a copy of the benchmark, runs through the weights, the FLOP
-  count and the reference with no harness file changed.
+  count and the reference with no harness file changed; its router, a
+  leaf that states float32 as its own dtype, is drawn, checked and
+  compared in float32 while the others stay in the configuration's
+  bfloat16.
+* A leaf table that states the router's float32, as the program keeps a
+  mixture of experts' router, fits the program's parameter tree in the
+  runner's check; one that leaves every leaf to the configuration's dtype
+  does not.
 * No harness file names a model's leaves, sizes or attention kind.
 """
 import hashlib
@@ -20,13 +27,14 @@ import numpy as np
 import pytest
 
 import tiny
-from harness import reference, spec, tokens, tree, weights
+from harness import reference, runner, spec, tokens, tree, weights
 from test_spec_cli import digests
 
 GOLDEN = json.loads((tiny.BENCH / "tests/data/dense_swa.golden.json")
                     .read_text())
 TOY = {"d_model": 64, "vocab": 96, "n_dense_layers": 1, "n_layers": 2,
-       "d_ff_dense": 160, "d_ff": 48, "param_dtype": "bfloat16"}
+       "d_ff_dense": 160, "d_ff": 48, "n_experts": 4,
+       "param_dtype": "bfloat16"}
 
 
 def smoke_config(m: dict, agents: int = 2) -> dict:
@@ -116,10 +124,11 @@ def test_a_model_with_two_stacks_runs_through_the_harness(tmp_path):
     shapes = model.leaf_shapes(m)
     flat = stacked(model, m, A, 2**31 + 7)
     assert {k: (v.shape, str(v.dtype)) for k, v in flat.items()} == {
-        k: ((A, *s), "bfloat16") for k, (s, _) in shapes.items()}
+        k: ((A, *e[0]), "float32" if k == "blocks/router/w" else "bfloat16")
+        for k, e in shapes.items()}
     assert flat["dense_blocks/mlp/up/w"].shape[-1] == TOY["d_ff_dense"]
     # 6 N over every matmul leaf and norm scale, the embedding out
-    n = sum(int(np.prod(s)) for k, (s, f) in shapes.items() if f >= 0)
+    n = sum(int(np.prod(e[0])) for e in shapes.values() if e[1] >= 0)
     assert model.train_flops_per_token(m, 128) == 6.0 * n
 
     devs = jax.devices()[:1]
@@ -127,6 +136,7 @@ def test_a_model_with_two_stacks_runs_through_the_harness(tmp_path):
     assert sorted(k.split("/")[0] for k in params[0]
                   if "/mlp/up/" in k) == ["blocks.0", "blocks.1",
                                           "dense_blocks.0"]
+    assert params[0]["blocks.1/router/w"].dtype == np.float32
     out = reference.run(model, params, stream(m, A, 2**31 + 7, 128),
                         cell.config, devs)
     assert np.isfinite(out["losses"]).all()
@@ -142,9 +152,9 @@ def test_split_and_join_layers_round_trip_two_stacks():
     flat = {k: np.asarray(v[0], np.float64) for k, v in
             stacked(toy, m, 1, 3).items()}
     split = reference.split_layers(flat, toy.stacks(m))
-    # three leaves a stack: the one layer of dense_blocks stays one, the
-    # two of blocks become two
-    assert len(split) == len(flat) + 3
+    # three leaves a stack and the router of blocks: the one layer of
+    # dense_blocks stays one, the two of blocks become two
+    assert len(split) == len(flat) + 4
     norms = reference.join_layers(
         {k: float(np.linalg.norm(v)) for k, v in split.items()},
         toy.stacks(m))
@@ -152,6 +162,44 @@ def test_split_and_join_layers_round_trip_two_stacks():
     for k, v in flat.items():
         assert norms[k] == pytest.approx(float(np.linalg.norm(v)),
                                          rel=1e-12)
+
+
+def test_a_float32_leaf_fits_the_program_tree():
+    """The program's mixture of experts (qwen3-moe-30b-a3b at its smoke
+    size) keeps its router in float32 and every other leaf in bfloat16."""
+    from repro.launch.train import build_trainer
+    from repro.training.train_step import abstract_train_state
+
+    trainer = build_trainer(arch="qwen3-moe-30b-a3b", smoke=True, agents=2,
+                            memory_mode="expsum", K=4, acc_dtype="bfloat16")
+    c = trainer.cfg
+    want = abstract_train_state(c, trainer.tc, 2).params
+    L, d, V, H, G, hd = (c.n_layers, c.d_model, c.vocab, c.n_heads,
+                         c.n_kv_heads, c.hd())
+    E, f = c.moe.n_experts, c.moe.expert_d_ff
+    shapes = {
+        "embed/table": ((V, d), -1), "ln_f/scale": ((d,), 0),
+        "lm_head/w": ((d, V), d),
+        "blocks/ln1/scale": ((L, d), 0), "blocks/ln2/scale": ((L, d), 0),
+        "blocks/attn/q_norm/scale": ((L, hd), 0),
+        "blocks/attn/k_norm/scale": ((L, hd), 0),
+        "blocks/attn/wq/w": ((L, d, H, hd), d),
+        "blocks/attn/wk/w": ((L, d, G, hd), d),
+        "blocks/attn/wv/w": ((L, d, G, hd), d),
+        "blocks/attn/wo/w": ((L, H, hd, d), H * hd),
+        "blocks/moe/experts/gate": ((L, E, d, f), d),
+        "blocks/moe/experts/up": ((L, E, d, f), d),
+        "blocks/moe/experts/down": ((L, E, f, d), f),
+        "blocks/moe/router/w": ((L, d, E), d, "float32"),
+    }
+
+    def have(table):
+        return jax.eval_shape(lambda k: weights.stacked_params(
+            k, table, c.param_dtype, 2), weights.seed_key(0))
+
+    assert runner.fits(want, have(shapes))
+    shapes["blocks/moe/router/w"] = shapes["blocks/moe/router/w"][:2]
+    assert not runner.fits(want, have(shapes))
 
 
 LEAF = re.compile(r"attn/|mlp/|\bblocks[/.]|embed/|lm_head|ln_f|ln1|ln2|"

@@ -111,3 +111,43 @@ def test_a_device_missing_from_the_peak_table_is_an_error(monkeypatch):
     assert len(runner.devices_for(1, True)) == 1
     with pytest.raises(runner.NoAccelerator):
         runner.devices_for(4, True)
+
+
+def test_a_reader_sees_what_the_program_counts(tmp_path):
+    """Readers added as new files get the program's ``obs.record`` records
+    of set-up and the window, and the trainer's step records of the window:
+    on the CPU the FrODO update takes jnp for every parameter, and with
+    chunks of two steps and ``log_every`` 5 every step of the window is
+    logged.  The sink set before the run is set again after it."""
+    import time
+
+    import numpy as np
+    from repro import obs
+
+    root = tiny.make_copy(tmp_path)
+    (root / "bench/metrics/jnp_params.py").write_text(
+        "def read(run):\n"
+        "    got = {r['name']: r['value'] for r in run['records']}\n"
+        "    assert got['frodo.fused_params'] == 0\n"
+        "    return got['frodo.jnp_params']\n")
+    (root / "bench/metrics/logged_steps.py").write_text(
+        "def read(run):\n"
+        "    return sum('loss' in h for h in run['history'])\n")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for name in ("jnp_params", "logged_steps"):
+        bench["per_layer"].append({
+            "name": name, "unit": "1", "better": "higher",
+            "source": "program_counter", "layer": "train step",
+            "moves": "train_tokens_per_s", "workloads": [tiny.CELL]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    before = obs.NullSink()
+    obs.set_sink(before)
+    result, _, _ = runner.run(root, tiny.CELL, 2**31 + 9, 0.5, True,
+                              time.perf_counter(),
+                              require_accelerator=False)
+    assert obs.get_sink() is before
+    got = {k: v["value"] for k, v in result["metrics"].items()}
+    n = sum(int(np.prod(e[0]))
+            for e in tiny.model().leaf_shapes(tiny.MODEL).values())
+    assert got == {"jnp_params": 2 * n, "logged_steps": result["attempted"]}

@@ -3,7 +3,10 @@ architecture would bring one (``bench/models/<model_code>.py``): a leading
 ``dense_blocks`` stack of ``n_dense_layers`` and a ``blocks`` stack of
 ``n_layers``, each layer a pre-norm SiLU MLP added to the residual stream,
 the MLP of the leading stack ``d_ff_dense`` wide and that of the other
-``d_ff``, then a final norm and an untied LM head."""
+``d_ff``, then a final norm and an untied LM head.  Each layer of
+``blocks`` also scales its MLP by a gate from an ``n_experts``-wide router
+that it keeps in float32, as a mixture of experts keeps its router: a leaf
+that states its own dtype."""
 from __future__ import annotations
 
 import jax
@@ -29,6 +32,8 @@ def leaf_shapes(m: dict) -> dict:
         shapes[f"{stack}/ln/scale"] = ((L, d), 0)
         shapes[f"{stack}/mlp/up/w"] = ((L, d, f), d)
         shapes[f"{stack}/mlp/down/w"] = ((L, f, d), f)
+    shapes["blocks/router/w"] = ((m["n_layers"], d, m["n_experts"]), d,
+                                 "float32")
     return shapes
 
 
@@ -36,6 +41,7 @@ def train_flops_per_token(m: dict, seq_len: int) -> float:
     d = m["d_model"]
     layers = sum(L * (2 * d * widths(m)[s] + d)
                  for s, L in stacks(m).items())
+    layers += m["n_layers"] * d * m["n_experts"]
     return 6.0 * (layers + d * m["vocab"] + d)
 
 
@@ -54,7 +60,11 @@ def rmsnorm(scale, x):
 def layer(bp, x, quant):
     h = rmsnorm(bp["ln/scale"], x)
     up = jax.nn.silu(mm("bsd,df->bsf", h, bp["mlp/up/w"], quant))
-    return x + mm("bsf,fd->bsd", up, bp["mlp/down/w"], quant)
+    out = mm("bsf,fd->bsd", up, bp["mlp/down/w"], quant)
+    if "router/w" in bp:
+        gate = jnp.tanh(mm("bsd,de->bse", h, bp["router/w"], quant))
+        out = out * (1.0 + jnp.mean(gate, -1, keepdims=True))
+    return x + out
 
 
 def head_loss(ln_f, w, x, labels, quant, half):
