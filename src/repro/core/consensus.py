@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.layout import default_layout
 from repro.obs import metrics as obs_metrics
 from repro.obs.timing import trace_scope
 
@@ -120,15 +121,23 @@ def mix_hierarchical(x: Pytree, W_intra: np.ndarray, W_pod: np.ndarray,
 
 
 def mix_uniform_constrained(tree: Pytree, specs: Pytree, mesh) -> Pytree:
-    """Uniform complete-graph consensus with explicit sharding constraints:
-    sum over the agent-sharded dim (lowers to an all-reduce among devices
-    sharing the model coords), constrain the mean to the agent-free spec,
-    then broadcast back to the stacked layout (no traffic).  This pins the
-    2x-local-bytes lowering; the unconstrained mean+broadcast lets the SPMD
-    partitioner pick an agent-dim all-gather (A x bytes) instead."""
+    """Uniform complete-graph consensus of agent-stacked leaves whose agent
+    dim is sharded over the mesh.  Every sum is float32 and the mean is
+    rounded once to the leaf's dtype.
+
+    A floating leaf takes an owner-computes mean (``_exchange``) where
+    ``_exchange_view`` finds one: only the leaf's own bytes cross the links,
+    about half of what a float32 all-reduce of a bf16 leaf moves (for a
+    float32 leaf, what a ring all-reduce moves).  Any other leaf sums over
+    the agent dim in float32 (an all-reduce among the devices that share
+    the model coords), constrains the mean to the agent-free spec and
+    broadcasts it back to the stacked layout; the constraints pin that
+    lowering, where an unconstrained mean and broadcast lets the SPMD
+    partitioner pick an agent-dim all-gather (A x the bytes).
+    ``record_uniform_split`` records which leaves take which path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    def leaf(v, sp):
+    def allreduce(v, sp):
         A = v.shape[0]
         rest = tuple(sp)[1:] if len(tuple(sp)) else ()
         m = jnp.sum(v.astype(jnp.float32), axis=0) / A
@@ -137,8 +146,123 @@ def mix_uniform_constrained(tree: Pytree, specs: Pytree, mesh) -> Pytree:
         out = jnp.broadcast_to(m[None], v.shape).astype(v.dtype)
         return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, sp))
 
+    def leaf(v, sp):
+        view = _exchange_view(v.shape, v.dtype, sp, mesh)
+        return (allreduce(v, sp) if view is None
+                else _exchange(v, sp, mesh, *view))
+
     with trace_scope("consensus.mix_uniform_constrained"):
         return jax.tree.map(leaf, tree, specs, is_leaf=lambda x: False)
+
+
+def record_uniform_split(tree: Pytree, specs: Pytree, mesh) -> None:
+    """Two records of ``mix_uniform_constrained``'s paths for ``tree``:
+    ``consensus.exchange_params`` and ``consensus.allreduce_params``, the
+    parameters of one agent on each, with ``leaves=``."""
+    flat, treedef = jax.tree.flatten(tree)
+    flat_s = treedef.flatten_up_to(specs)
+    exchanged = [_exchange_view(v.shape, v.dtype, sp, mesh) is not None
+                 for v, sp in zip(flat, flat_s)]
+    for name, on in (("consensus.exchange_params", True),
+                     ("consensus.allreduce_params", False)):
+        sizes = [int(np.prod(v.shape[1:]))
+                 for v, ex in zip(flat, exchanged) if ex == on]
+        obs_metrics.record(name, sum(sizes), leaves=len(sizes))
+
+
+def _exchange_view(shape, dtype, spec, mesh) -> Optional[tuple]:
+    """How one agent-stacked leaf of ``shape`` (agent dim first, sharded by
+    ``spec`` over ``mesh``) takes ``_exchange``, as (agent mesh axis, the
+    local shard's dims major to minor in the device's default layout, view
+    shape, view permutation); None keeps the float32 all-reduce.
+
+    The exchange moves A contiguous chunks of each device's shard.  The
+    shard, put in its layout order (a bitcast), is viewed as (A, rows / A,
+    ...) by merging its most major dims up to the first prefix whose size
+    A divides; a tiled dim may end the prefix only where the chunk keeps
+    whole tiles, and the prefix may merge into it only where it holds whole
+    tiles.  A shard within one tile row splits its minor dim instead.  So
+    no operand is copied, where a flat reshape of a tiled leaf would be.
+
+    The all-reduce stays for a leaf that is not floating, whose agent dim
+    spans several mesh axes, that another mesh axis of size > 1 also
+    shards, or that no such view divides."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return None
+    sizes = mesh.shape
+    axes = [() if p is None else p if isinstance(p, tuple) else (p,)
+            for p in tuple(spec)]
+    A = shape[0]
+    if (not axes or len(axes[0]) != 1 or sizes[axes[0][0]] != A or A < 2
+            or any(sizes[a] > 1 for dim in axes[1:] for a in dim)):
+        return None
+    local = (1,) + tuple(shape[1:])
+    layout = default_layout(local, dtype, mesh.devices.flat[0])
+    order = tuple(layout.major_to_minor)
+    u = [local[d] for d in order]
+    n = len(u)
+    tile = tuple(layout.tiling[0]) if layout.tiling else ()
+    first_tiled = n - len(tile)
+    t = (1,) * first_tiled + tile
+    rows = 1
+    for j in range(min(n, first_tiled + 1)):
+        if j == first_tiled and rows > 1 and u[j] % t[j]:
+            break
+        rows *= u[j]
+        if rows % (A * t[j]) == 0:
+            return (axes[0][0], order, (A, rows // A) + tuple(u[j + 1:]),
+                    tuple(range(n + 1 - j)))
+    if (len(tile) == 2 and int(np.prod(u[:-2])) == 1 and u[-2] <= t[-2]
+            and u[-1] % (A * t[-1]) == 0):
+        return (axes[0][0], order, (u[-2], A, u[-1] // A), (1, 0, 2))
+    return None
+
+
+def _exchange(v: jax.Array, spec, mesh, axis: str, order: tuple,
+              view: tuple, perm: tuple) -> jax.Array:
+    """The uniform mean of one agent-stacked leaf (sharded by ``spec``) as
+    an owner-computes exchange.  Each agent's shard, in its layout order
+    (a bitcast), is viewed as A chunks; chunk j of every agent is resharded
+    to device j (an all-to-all, in the leaf's dtype); device j adds the A
+    chunks in float32, divides by A and rounds once to the dtype; and every
+    device gathers the rounded chunks (an all-gather).  Never a reduction
+    in the narrow dtype, which would round the partial sums.  The steps are
+    sharding constraints that the SPMD partitioner lowers to XLA's
+    collectives, which keep their opcodes' names (``all-to-all.3``) on a
+    device trace; a ``shard_map``'s explicit collectives are named after
+    JAX's primitives (``all_to_all.3``).  ``_exchange_view`` gives
+    ``axis``, ``order``, ``view`` and ``perm``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    A = v.shape[0]
+
+    def pin(x, sp):
+        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, sp))
+
+    def inverse(p):
+        return (0,) + tuple(1 + int(d) for d in np.argsort(p))
+
+    # (A, 1, ...): ``order`` ranks the local shard's dims, its agent dim
+    # (size 1 there) included, so the leaf takes a dim beside that one.
+    # The view is pinned to the agent sharding on both sides of the view's
+    # reshape, so that no later constraint reaches back through it (and
+    # into the update) as a sharding of the merged dims.
+    u = jnp.transpose(pin(v, spec)[:, None],
+                      (0,) + tuple(1 + d for d in order))
+    y = pin(jnp.reshape(u, (A,) + view), P(axis))
+    y = pin(jnp.transpose(y, (0,) + tuple(1 + d for d in perm)),
+            P(None, axis))
+    m = (jnp.sum(y.astype(jnp.float32), axis=0) / A).astype(v.dtype)
+    z = pin(jnp.broadcast_to(pin(m, P())[None], y.shape), P(axis))
+    # XLA writes no collective's result into a donated buffer: the step's
+    # output would be a copy of the gathered mean, and the donated
+    # parameter would be copied too, at the step's start.  An elementwise
+    # pass (rounding to the dtype it has, the identity, which XLA keeps)
+    # writes the result in place of that copy.
+    finfo = jnp.finfo(z.dtype)
+    z = jax.lax.reduce_precision(z, finfo.nexp, finfo.nmant)
+    z = jnp.reshape(jnp.transpose(z, inverse(perm)), u.shape)
+    return pin(jnp.reshape(jnp.transpose(z, inverse(order)), v.shape), spec)
 
 
 def pmean_shardmap(tree: Pytree, agent_axes, mesh) -> Pytree:

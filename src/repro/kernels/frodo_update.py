@@ -37,8 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.layout import Layout
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.layout import default_layout
 
 LANE = 128
 SUBLANE = 16                 # row multiple that tiles both f32 and bf16
@@ -156,20 +157,15 @@ def default_device():
     return dev if dev is not None else jax.devices()[0]
 
 
-def _major_to_minor(shape, dtype, device) -> tuple:
-    layout = device.client.get_default_layout(jnp.dtype(dtype), tuple(shape),
-                                              device)
-    return Layout.from_pjrt_layout(layout).major_to_minor
-
-
 def expsum_order(shape, dtype, acc_dtype, K: int, device) -> tuple | None:
     """The major-to-minor order of a leaf's dims in ``device``'s default
     layout, where ``expsum_apply`` takes the leaf (of ``dtype``, with K
     accumulators of ``acc_dtype``): the accumulators' default layout is
     the leaf's behind a major K dim, and the leaf, so ordered, tiles
     (``expsum_block``).  None keeps the leaf on jnp."""
-    order = _major_to_minor(shape, dtype, device)
-    acc_order = _major_to_minor((K,) + tuple(shape), acc_dtype, device)
+    order = default_layout(shape, dtype, device).major_to_minor
+    acc_order = default_layout((K,) + tuple(shape), acc_dtype,
+                               device).major_to_minor
     if acc_order != (0,) + tuple(d + 1 for d in order):
         return None
     if expsum_block(tuple(shape[d] for d in order),

@@ -284,6 +284,7 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, n_agents: int,
     # updates (faults) and the optimizer's metrics take update + apply
     fused_apply = (opt.apply is not None and faults is None
                    and not tc.collect_metrics)
+    mix_logged = []     # the mix's split is recorded on the first trace
 
     def agent_grad_fn(params1, batch1):
         """Per-agent (loss, metrics), grads — microbatched grad accumulation
@@ -384,6 +385,9 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, n_agents: int,
                     shapes = jax.eval_shape(lambda p: p, params)
                     specs = param_specs(shapes, rules, mesh,
                                         agent_stacked=True)
+                    if not mix_logged:
+                        mix_logged.append(True)
+                        C.record_uniform_split(shapes, specs, mesh)
                     return C.mix_uniform_constrained(params, specs, mesh)
                 return C.mix_stacked(params, W)
             if tc.consensus_interval > 1:

@@ -224,10 +224,10 @@ def test_one_chip_step_reads_each_accumulator_once(one_chip):
     assert found >= len(tiled)
 
 
-def test_four_chip_update_adds_no_collective(topology):
+@pytest.fixture(scope="module")
+def four_chip_step(topology):
     """The four-agent step over a (4, 1) ``data`` x ``model`` mesh of the
-    2x2 chips: the fused update runs per shard, so inside ``frodo.update``
-    the only collective is the clip norm's scalar all-reduce."""
+    2x2 chips, state donated: (compiled text, abstract state)."""
     mesh = Mesh(np.array(topology.devices).reshape(4, 1), ("data", "model"))
     trainer = build_trainer(**FOUR_CHIP_TRAIN, mesh=mesh)
     state = jax.tree.map(
@@ -239,14 +239,90 @@ def test_four_chip_update_adds_no_collective(topology):
     rows = NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
     batch = {k: jax.ShapeDtypeStruct((A, B, S), jnp.int32, sharding=rows)
              for k in ("tokens", "labels")}
-    hlo = trainer.step_fn.lower(state, batch).compile().as_text()
+    return trainer.step_fn.lower(state, batch).compile().as_text(), state
+
+
+def test_four_chip_update_adds_no_collective(four_chip_step):
+    """The fused update runs per shard, so inside ``frodo.update`` the only
+    collective is the clip norm's scalar all-reduce (which XLA may combine
+    with other scalars' into one all-reduce of a tuple)."""
+    hlo, _ = four_chip_step
     assert "tpu_custom_call" in hlo
-    update = [ln for ln in hlo.splitlines() if "frodo.update" in ln]
-    gathers = [ln for ln in update if re.search(r"all-gather", ln)]
-    reduces = [ln.split("=", 1)[1].split(" ")[1] for ln in update
-               if re.search(r" all-reduce(-start)?\(", ln)]
+    entry = _entry(hlo)
+    update = [(shape, rest) for _, shape, rest in entry
+              if "frodo.update" in rest]
+    gathers = [rest for _, rest in update if rest.startswith("all-gather")]
+    reduces = [shape for shape, rest in update
+               if re.match(r"all-reduce(-start)?\(", rest)]
     assert not gathers, gathers
-    assert all(r.startswith("f32[]") for r in reduces), reduces
+    assert reduces and all(re.findall(r"\w+\[[\d,]*\]", r) and all(
+        a == "f32[]" for a in re.findall(r"\w+\[[\d,]*\]", r))
+        for r in reduces), reduces
+
+
+def _elements(shape: str) -> int:
+    return int(np.prod([int(n) for n in re.findall(
+        r"\d+", shape.split("]")[0].split("[")[-1])]))
+
+
+def test_four_chip_mix_moves_bf16(four_chip_step):
+    """Inside ``consensus.*`` each parameter leaf crosses the links as an
+    all-to-all and an all-gather of bf16 (on the CPU XLA widens bf16
+    collectives to f32, so only this compile shows the wire dtype), each
+    named after its opcode, and no all-reduce of a parameter's size is
+    left.  For the matrices (a million parameters or more a chip), no copy
+    or transpose feeds a collective or reads its result, and the chunks
+    that the all-to-all brings are read once, by fusions that sum them in
+    float32."""
+    hlo, state = four_chip_step
+    entry = _entry(hlo)
+    text = {name: rest for name, _, rest in entry}
+    kind = {name: re.match(r"[\w\-]+", rest).group(0)
+            for name, _, rest in entry}
+    users = {}
+    for name, _, rest in entry:
+        for op in re.findall(r"%([\w.\-]+)", rest.split(", metadata")[0]):
+            users.setdefault(op, []).append(name)
+    mix = [(name, shape) for name, shape, rest in entry
+           if "consensus." in rest]
+    wire = [(name, shape) for name, shape in mix
+            if kind[name].startswith(("all-to-all", "all-gather"))]
+    leaves = jax.tree.leaves(state.params)
+    assert len(wire) == 2 * len(leaves), wire
+    assert all(shape.startswith("bf16[") for _, shape in wire), wire
+    # a device trace names an operation by its instruction: each of the
+    # mix's collectives is named after its opcode
+    assert all(name.startswith(kind[name]) for name, _ in wire), wire
+    smallest = min(p.size // p.shape[0] for p in leaves)
+    reduces = [shape for name, shape in mix
+               if kind[name].startswith("all-reduce")
+               and _elements(shape) >= smallest]
+    assert not reduces, reduces
+    fused = {head.group(1): head.group(2) for head in re.finditer(
+        r"\n%(\S+) [^\n]*\{\n(.*?)\n\}", hlo, re.S)}
+    matrices = [(name, shape) for name, shape in wire
+                if _elements(shape) >= 2 ** 20]
+    assert len(matrices) >= 2 * 5, wire
+    for name, _ in matrices:
+        near = re.findall(r"%([\w.\-]+)", text[name].split(", metadata")[0])
+        near += users.get(name, [])
+        assert not [n for n in near
+                    if kind[n] in ("copy", "transpose")], (name, near)
+        if not kind[name].startswith("all-to-all"):
+            continue
+        frontier, readers = [name], []
+        while frontier:
+            for u in users.get(frontier.pop(), []):
+                if kind[u] in ("bitcast", "slice-start", "slice-done") or (
+                        'custom_call_target="ConcatBitcast"' in text[u]):
+                    frontier.append(u)
+                else:
+                    readers.append(u)
+        assert readers and all(kind[u] == "fusion" for u in readers), \
+            (name, readers)
+        for u in readers:
+            body = fused[re.search(r"calls=%([\w.\-]+)", text[u]).group(1)]
+            assert re.search(r"= f32\[[^\n]* reduce\(", body), (name, body)
 
 
 def hlo_scopes(hlo: str) -> dict:

@@ -93,20 +93,27 @@ def test_mesh_trainer_matches_plain_trainer():
 def test_agents_spread_over_four_host_devices(tmp_path):
     """Four virtual CPU devices (set before JAX starts, hence the child
     process): one agent per device, same per-agent losses as one device,
-    agents with bit-identical parameters after the mix, and a mix that is
-    an all-reduce, not a gather."""
+    agents with bit-identical parameters after the mix, and a mix that
+    moves each agent's own bytes: an exchange of the leaves whose only
+    all-gathers are one a leaf, each of one agent's elements (a gather of
+    the agent dim holds A x those), no all-reduce in the mix as large as a
+    parameter leaf (the float32 path's), and its split recorded once."""
     script = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import json, re
         import jax, numpy as np
         from repro.launch.train import run_training
+        from repro.obs import metrics as obs_metrics
         kw = dict(smoke=True, agents=4, steps=2, seq=16, batch_per_agent=1,
                   memory_mode="expsum", K=2, acc_dtype="bfloat16")
         losses = {}
+        sink = obs_metrics.MemorySink()
         for mesh in (False, True):
             out = os.path.join(os.environ["OUT"], f"m{mesh}.jsonl")
+            prev = obs_metrics.set_sink(sink if mesh else None)
             trainer, state = run_training(mesh=mesh, metrics_out=out, **kw)
+            obs_metrics.set_sink(prev)
             losses[mesh] = [json.loads(l)["agent_loss"] for l in open(out)]
         apart = max(float(np.abs(np.asarray(sh.data, np.float32)
                                  - np.asarray(leaf.addressable_shards[0].data,
@@ -121,13 +128,28 @@ def test_agents_spread_over_four_host_devices(tmp_path):
         batch = {k: np.zeros((4, 1, 16), np.int32)
                  for k in ("tokens", "labels")}
         hlo = trainer.step_fn.lower(state, batch).compile().as_text()
+
+        def results(op, scope=""):
+            # the result shapes of every ``op`` (a tuple's each) whose
+            # op_name holds ``scope``
+            return [[int(n) for n in dims.split(",") if n]
+                    for m in re.finditer(r"= (\\(.*?\\)|\\S+) " + op
+                                         + r"(-start)?\\(.*", hlo)
+                    if scope in m.group(0)
+                    for dims in re.findall(r"\\w+\\[([\\d,]*)\\]",
+                                           m.group(1))]
         print(json.dumps({
             "losses": {str(k): v for k, v in losses.items()},
             "per_dev": list(per_dev.values()),
             "total": sum(l.nbytes for l in jax.tree.leaves(state)),
             "apart": apart,
-            "all_reduce": len(re.findall(r"all-reduce(-start)?\\(", hlo)),
-            "all_gather": len(re.findall(r"all-gather(-start)?\\(", hlo))}))
+            "leaves": [[list(l.shape), str(l.dtype)]
+                       for l in jax.tree.leaves(state.params)],
+            "all_to_all": len(results("all-to-all")),
+            "mix_all_reduce": results("all-reduce", "consensus."),
+            "all_gather": results("all-gather"),
+            "split": [r for r in sink.records
+                      if r["name"].startswith("consensus.")]}))
     """)
     env = dict(os.environ, OUT=str(tmp_path), JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
@@ -143,7 +165,16 @@ def test_agents_spread_over_four_host_devices(tmp_path):
     assert len(out["per_dev"]) == 4
     assert max(out["per_dev"]) <= out["total"] / 4 * 1.001
     assert out["apart"] == 0
-    assert out["all_reduce"] > 0 and out["all_gather"] == 0
+    one_agent = [int(np.prod(shape[1:])) for shape, _ in out["leaves"]]
+    assert out["all_to_all"] > 0
+    # one all-gather a leaf, of the mean: one agent's elements, never A x
+    assert sorted(int(np.prod(r)) for r in out["all_gather"]) == sorted(
+        one_agent), out["all_gather"]
+    assert not [r for r in out["mix_all_reduce"]
+                if np.prod(r) >= min(one_agent)], out["mix_all_reduce"]
+    assert [(r["name"], r["value"], r["leaves"]) for r in out["split"]] == [
+        ("consensus.exchange_params", sum(one_agent), len(one_agent)),
+        ("consensus.allreduce_params", 0, 0)]
 
 
 def test_layer_cut_keeps_published_widths():
